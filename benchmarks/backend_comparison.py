@@ -234,6 +234,9 @@ def _check_pq(records, by, largest, args) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sizes", type=str, default="8192,24576,65536",
                     help="comma-separated corpus sizes")

@@ -29,6 +29,12 @@ or explicit file surgery (never racing real hardware faults), recorded to
    a mutation is honoured on every replica) and a deterministic
    ``replica_apply`` fault-injection sub-check.
 
+This is a harness for WAL and replication semantics, not a device path.
+It runs on the CPU, and so does every child it starts: ``JAX_PLATFORMS=cpu``
+is set before the parent's first JAX import and in each child's
+environment.  On a host with a chip, the parent would otherwise hold the
+chip and its server children would fail on the TPU runtime's lock.
+
 Exit status is non-zero if any check fails.  ``--smoke`` (CI) shrinks the
 corpus and the storm but enforces every check — all six phases are
 deterministic, so nothing is skipped:
@@ -48,10 +54,13 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np
 
 WAIT = 60.0
+# every child process runs on the CPU too (see the module docstring)
+CPU_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 FAST_FT = dict(heartbeat_timeout_s=0.2, backoff_initial_s=0.01,
                backoff_max_s=0.05)
 
@@ -121,7 +130,7 @@ def phase_sigkill(args, state: str) -> dict:
     code = CHILD.format(src=src, d=args.dim, state=state,
                         snap_at=args.churn_snapshot_at)
     proc = subprocess.Popen([sys.executable, "-c", code],
-                            stdout=subprocess.PIPE)
+                            stdout=subprocess.PIPE, env=CPU_ENV)
     try:
         assert proc.stdout.readline().strip() == b"ready"
         time.sleep(args.churn_s)
@@ -339,7 +348,7 @@ def _spawn_server(role: str, state: str, port: int, dim: int, log_path: str,
            "--allow-anonymous", "--docs", "0", "--d-emb", str(dim)]
     if snapshot_every_s > 0:
         cmd += ["--snapshot-every-s", str(snapshot_every_s)]
-    env = dict(os.environ, PYTHONPATH=src)
+    env = dict(CPU_ENV, PYTHONPATH=src)
     log = open(log_path, "ab")
     return subprocess.Popen(cmd, stdout=log, stderr=subprocess.STDOUT,
                             env=env)

@@ -117,6 +117,9 @@ def run_driver_config(db, queries, buckets, *, max_wait_ms, clients,
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--docs", type=int, default=20000)
     ap.add_argument("--dim", type=int, default=256)

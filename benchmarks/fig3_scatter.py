@@ -10,6 +10,7 @@ from benchmarks.common import (load_corpus, print_csv, progressive_row,
                                std_args, timed_median, truncated_row)
 from repro.core import (make_schedule, progressive_search_pooled,
                         top1_accuracy)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run(args=None):
@@ -72,4 +73,5 @@ def run(args=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(std_args(__doc__).parse_args())

@@ -42,6 +42,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import http_json
 from repro.obs import parse_prometheus, summarize_latency
 
@@ -174,6 +175,7 @@ def run_churn(url, tenant, dim, universe, universe_lock, stop, rng,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tenants", type=int, default=2,
                     help="isolated namespaces driven concurrently (>= 2)")

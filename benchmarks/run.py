@@ -21,6 +21,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from benchmarks.common import std_args
+from repro.launch.compile_cache import enable_compile_cache
 
 # Which committed perf record each benchmark module refreshes.  CI's
 # bench-smoke job runs every one of these with --smoke and uploads
@@ -55,6 +56,7 @@ def print_bench_manifest() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = std_args(__doc__)
     ap.add_argument("--list-bench", action="store_true",
                     help="list the BENCH_*.json records the suite refreshes "

@@ -7,6 +7,7 @@ to choose truncation.
 
 
 from benchmarks.common import load_corpus, print_csv, std_args, truncated_row
+from repro.launch.compile_cache import enable_compile_cache
 
 PAPER_GTE = {16: 6.56, 32: 39.55, 64: 78.42, 128: 88.79, 256: 92.79,
              512: 93.81, 1024: 94.49, 2048: 94.82, 3072: 94.98, 3584: 95.02}
@@ -50,4 +51,5 @@ def run(args=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(std_args(__doc__).parse_args())

@@ -8,6 +8,7 @@ as truncated-at-d_max with substantially lower runtime (2x at mid dims,
 from benchmarks.common import (clamp_configs, load_corpus, print_csv,
                                progressive_row, std_args, truncated_row)
 from repro.core import build_index, stage_dims, make_schedule
+from repro.launch.compile_cache import enable_compile_cache
 
 # (trunc_dim, (d_start, d_max, k0)) pairs; scaled from the paper's
 # (256,(128,512,128)), (512,(128,2048,16)), (1024,(128,3584,64)),
@@ -60,4 +61,5 @@ def run(args=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(std_args(__doc__).parse_args())

@@ -3,6 +3,7 @@
 truncation points, so low dims carry relatively more signal)."""
 
 from benchmarks.common import load_corpus, print_csv, std_args, truncated_row
+from repro.launch.compile_cache import enable_compile_cache
 
 PAPER_OPENAI = {16: 3.32, 32: 29.35, 64: 70.73, 128: 88.18, 256: 92.02,
                 512: 93.40, 1024: 93.85, 2048: 94.17, 3072: 94.45}
@@ -26,4 +27,5 @@ def run(args=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(std_args(__doc__).parse_args())

@@ -3,6 +3,7 @@
 from benchmarks.common import (clamp_configs, load_corpus, print_csv,
                                progressive_row, std_args, truncated_row)
 from repro.core import build_index, make_schedule, stage_dims
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def configs_for(d_full: int):
@@ -43,4 +44,5 @@ def run(args=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     run(std_args(__doc__).parse_args())

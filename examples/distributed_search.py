@@ -33,8 +33,8 @@ def main():
     sched = make_schedule(64, 256, 128)
     idx = build_index(db, stage_dims(sched))
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     for mode in ("local", "global"):
         t0 = time.perf_counter()
         s, i = sharded_progressive_search(

@@ -15,7 +15,7 @@ below — which is the paper's "future work: integration with ANN" realized.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -33,6 +33,7 @@ def balanced_assign(
     confidence_order: np.ndarray,
     n_lists: int,
     cap: int,
+    rank_rest: Optional[Callable[[np.ndarray], np.ndarray]] = None,
 ) -> np.ndarray:
     """Capacity-bounded list assignment (host-side, build time).
 
@@ -46,46 +47,58 @@ def balanced_assign(
     Rows are admitted to their most-preferred list with free capacity,
     confident rows first (a row whose nearest centroid is far away loses
     little by being displaced to its 2nd/3rd choice; a row close to its
-    centroid should stay).  Rows exhausting all ``m`` choices spill into
-    whatever lists still have spare capacity, lowest-indexed first (rare
-    under a sane cap; every list stays bounded at ``cap`` regardless).
+    centroid should stay).  Rows that find all ``m`` choices full take the
+    first list with room in the full order ``rank_rest`` gives them.  A
+    caller whose lists can fill should rank by distance: a few hub
+    centroids that are every row's first choices fill up early in high
+    dimensions, and a row spilled to a far list is one no probe near it
+    will look at.  Every list stays bounded at ``cap``.
 
     Args:
       choices:          (N, m) int centroid preference order per row.
       confidence_order: (N,) row indices, most-confident first.
       n_lists:          number of lists.
       cap:              max members per list; needs n_lists * cap >= N.
+      rank_rest:        maps (R,) row indices to their (R, n_lists) full
+                        centroid preference order (default: list index
+                        order).
 
     Returns:
       (N,) int32 list assignment.
     """
-    n, m = choices.shape
+    n = choices.shape[0]
     if n_lists * cap < n:
         raise ValueError(f"cap {cap} x {n_lists} lists cannot hold {n} rows")
     assign = np.full(n, -1, np.int32)
     counts = np.zeros(n_lists, np.int64)
-    rank = np.empty(n, np.int64)
-    rank[confidence_order] = np.arange(n)
-    remaining = confidence_order.copy()
-    for j in range(m):
-        if remaining.size == 0:
-            break
-        pref = choices[remaining, j]
-        # stable-sort by list, keeping confidence order within each list,
-        # then admit each list's first (cap - occupancy) rows
-        by_list = np.argsort(pref, kind="stable")
-        pref_sorted = pref[by_list]
-        group_start = np.searchsorted(pref_sorted, pref_sorted)
-        pos_in_group = np.arange(remaining.size) - group_start
-        admit = pos_in_group < (cap - counts[pref_sorted])
-        rows = remaining[by_list[admit]]
-        assign[rows] = pref_sorted[admit]
-        np.add.at(counts, pref_sorted[admit], 1)
-        remaining = remaining[by_list[~admit]]
-        remaining = remaining[np.argsort(rank[remaining])]  # restore order
+
+    def admit(prefs, rows):
+        # rows (R,) in confidence order, prefs (R, w) their preferences;
+        # returns the rows that no column admitted, still in order
+        for j in range(prefs.shape[1]):
+            if rows.size == 0:
+                break
+            pref = prefs[:, j]
+            # stable-sort by list, keeping confidence order within each
+            # list, then admit each list's first (cap - occupancy) rows
+            by_list = np.argsort(pref, kind="stable")
+            pref_sorted = pref[by_list]
+            group_start = np.searchsorted(pref_sorted, pref_sorted)
+            pos_in_group = np.arange(rows.size) - group_start
+            ok = pos_in_group < (cap - counts[pref_sorted])
+            assign[rows[by_list[ok]]] = pref_sorted[ok]
+            np.add.at(counts, pref_sorted[ok], 1)
+            keep = np.sort(by_list[~ok])                # restore order
+            rows, prefs = rows[keep], prefs[keep]
+        return rows
+
+    if rank_rest is None:
+        def rank_rest(rows):
+            return np.broadcast_to(np.arange(n_lists), (rows.size, n_lists))
+
+    remaining = admit(choices[confidence_order], confidence_order)
     if remaining.size:
-        free = np.repeat(np.arange(n_lists), cap - counts)
-        assign[remaining] = free[: remaining.size].astype(np.int32)
+        admit(rank_rest(remaining), remaining)
     return assign
 
 
@@ -304,14 +317,14 @@ def _sq_col(sq_prefix, index_dims, dim: int):
 @functools.partial(
     jax.jit,
     static_argnames=("sched", "n_probe", "index_dims", "metric",
-                     "pack_meta", "merge", "pq_oversample", "interpret",
+                     "pack_meta", "pq_oversample", "interpret",
                      "stage0_only"),
 )
 def _kernel_search_jit(
     q, db, centroids, lists, pack_rows, pack_sq, pack_scale,
     pack_codebooks, pack_cent_sq,
     valid, sq_prefix, extra_cand, cent_sq, sched,
-    *, n_probe, index_dims, metric, pack_meta, merge, pq_oversample,
+    *, n_probe, index_dims, metric, pack_meta, pq_oversample,
     interpret, stage0_only=False,
 ):
     from repro.kernels.ivf_scan import ivf_scan_topk
@@ -342,13 +355,11 @@ def _kernel_search_jit(
         # noise — the full-precision rescore ladder cuts it back
         k0_eff = s0.k * pq_oversample
         scores, cand = pq_ivf_scan_topk(
-            q, probe, member_ids, pack, k=k0_eff, merge=merge,
-            interpret=interpret)
+            q, probe, member_ids, pack, k=k0_eff, interpret=interpret)
     else:
         k0_eff = s0.k
         scores, cand = ivf_scan_topk(
-            q, probe, member_ids, pack, k=k0_eff, merge=merge,
-            interpret=interpret)
+            q, probe, member_ids, pack, k=k0_eff, interpret=interpret)
 
     if extra_cand is not None:
         # the un-indexed tail window competes in stage 0 exactly as the XLA
@@ -395,7 +406,6 @@ def ivf_progressive_search_kernel(
     metric: str = "l2",
     cent_sq: Optional[Array] = None,
     pack: Optional[Dict] = None,
-    merge: str = "sort",
     block_m: int = 128,
     pq_oversample: int = 1,
     interpret: bool = False,
@@ -419,7 +429,6 @@ def ivf_progressive_search_kernel(
       pack:      `pack_ivf_lists` build artifact (member slabs at the
                  stage-0 dim; pass the cached one from backend state — when
                  None it is packed on the fly, which costs a full gather).
-      merge:     in-kernel top-k merge strategy ('sort' | 'select').
       block_m:   member rows per kernel step (on-the-fly packs only).
       pq_oversample: 'pq' packs only — stage-0 survivor pool widens to
                  ``pq_oversample × k0`` (ADC ranking noise is absorbed by
@@ -445,6 +454,6 @@ def ivf_progressive_search_kernel(
         pack.get("codebooks"), pack.get("cent_sq"),
         valid, sq_prefix, extra_cand, cent_sq, sched,
         n_probe=n_probe, index_dims=index_dims, metric=metric,
-        pack_meta=pack_meta, merge=merge, pq_oversample=pq_oversample,
+        pack_meta=pack_meta, pq_oversample=pq_oversample,
         interpret=interpret, stage0_only=stage0_only,
     )

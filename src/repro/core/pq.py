@@ -40,15 +40,21 @@ Array = jax.Array
 
 
 def auto_pq_m(d0: int) -> int:
-    """Default subspace count for a ``d0``-dim stage-0 block: aim dsub = 8.
+    """Default subspace count for a ``d0``-dim stage-0 block: aim dsub = 4.
 
-    ``d0 // 8`` when that divides evenly (8-dim subspaces quantize well at
-    256 codes); otherwise a single subspace — coarse, but the progressive
-    rescore runs at full precision either way, and an explicit ``pq_m`` is
-    always available.
+    ``d0 // 4`` when that divides evenly; otherwise a single subspace —
+    coarse, but the progressive rescore runs at full precision either way,
+    and an explicit ``pq_m`` is always available.  Codes cover only the
+    stage-0 prefix while the full-precision rows stay resident for the
+    rescore, so 4-dim subspaces cost a row a few more bytes of a budget the
+    rescore store dominates.  What they buy is the stage-0 pool: on
+    topical data the 256 codes of an 8-dim subspace settle on the topic
+    centres, and at the paper deployment's 3584 dims cut to 262,144 rows
+    (``chip_smoke.py``) 8-dim codes left the source row of 1 in 32 noisy
+    copies outside the oversampled pool.
     """
-    if d0 >= 16 and d0 % 8 == 0:
-        return d0 // 8
+    if d0 >= 8 and d0 % 4 == 0:
+        return d0 // 4
     return 1
 
 
@@ -308,7 +314,7 @@ def pq_progressive_search(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("sched", "metric", "merge", "block_m", "oversample",
+    static_argnames=("sched", "metric", "block_m", "oversample",
                      "interpret", "stage0_only"))
 def pq_progressive_search_kernel(
     q: Array, idx: Dict[str, Array], sched: ProgressiveSchedule,
@@ -317,7 +323,6 @@ def pq_progressive_search_kernel(
     valid: Optional[Array] = None,
     row_limit: Optional[Array] = None,
     extra_cand: Optional[Array] = None,
-    merge: str = "sort",
     block_m: int = 128,
     oversample: int = 1,
     interpret: bool = False,
@@ -346,7 +351,7 @@ def pq_progressive_search_kernel(
     ids = _stage0_ids(codes, valid, row_limit)
     scores, cand = pq_scan_topk(
         lut, codes, ids, k=min(s0.k * oversample, n0), block_m=block_m,
-        merge=merge, interpret=interpret)
+        interpret=interpret)
     return _finish(q, rescore_db, sched, scores, cand,
                    valid=valid, extra_cand=extra_cand, metric=metric,
                    stage0_only=stage0_only)

@@ -85,7 +85,6 @@ class IVFConfig(BackendConfig):
     use_kernel: Union[str, bool] = "auto"
     stage0_dtype: str = "float32"
     kernel_block_m: int = 128
-    kernel_merge: str = "sort"
     pq_m: Optional[int] = None
     pq_codes: int = 256
     pq_iters: int = 10
@@ -95,7 +94,6 @@ class IVFConfig(BackendConfig):
     def __post_init__(self):
         _validate_choice(self, "stage0_dtype", ("float32", "int8", "pq"))
         _validate_choice(self, "use_kernel", ("auto", True, False))
-        _validate_choice(self, "kernel_merge", ("sort", "select"))
         _validate_positive(
             self, "n_lists", "n_probe", "kmeans_iters", "train_rows",
             "tail_window", "kernel_block_m", "pq_m", "pq_codes",
@@ -128,13 +126,11 @@ class QuantizedConfig(BackendConfig):
     encode_appends: bool = True
     use_kernel: Union[str, bool] = "auto"
     kernel_block_m: int = 128
-    kernel_merge: str = "sort"
     seed: int = 0
 
     def __post_init__(self):
         _validate_choice(self, "codec", ("int8", "pq"))
         _validate_choice(self, "use_kernel", ("auto", True, False))
-        _validate_choice(self, "kernel_merge", ("sort", "select"))
         _validate_positive(
             self, "tail_window", "kernel_block_m", "pq_m", "pq_codes",
             "pq_train_rows", "pq_oversample")
@@ -582,7 +578,7 @@ class EngineConfig:
                         choices=("int8", "pq"),
                         help="quantized only: stage-0 code block codec")
         ap.add_argument("--pq-m", type=int, default=0,
-                        help="PQ subspaces per row (0 = auto, aim 8-dim "
+                        help="PQ subspaces per row (0 = auto, aim 4-dim "
                              "subspaces); must divide the stage-0 dim")
         ap.add_argument("--rebuild-mode", type=str, default="sync",
                         choices=("sync", "background", "off"))

@@ -109,7 +109,6 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
         use_kernel="auto",
         stage0_dtype: str = "float32",
         kernel_block_m: int = 128,
-        kernel_merge: str = "sort",
         pq_m: Optional[int] = None,
         pq_codes: int = 256,
         pq_iters: int = 10,
@@ -157,9 +156,8 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
                         table, the fused probe+LUT-scan.  Both require
                         the kernel path).
         kernel_block_m: member rows per kernel step.
-        kernel_merge:   in-kernel top-k merge ('sort' | 'select').
         pq_m:           'pq' only: subspaces per stage-0 row (None: aim
-                        8-dim subspaces — `repro.core.pq.auto_pq_m`); must
+                        4-dim subspaces — `repro.core.pq.auto_pq_m`); must
                         divide the stage-0 dim.
         pq_codes:       'pq' only: centroids per subspace (<= 256).
         pq_iters:       'pq' only: k-means iterations per subspace.
@@ -195,7 +193,6 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
         self.use_kernel = use_kernel
         self.stage0_dtype = stage0_dtype
         self.kernel_block_m = int(kernel_block_m)
-        self.kernel_merge = kernel_merge
         self.pq_codes = int(pq_codes)
         self.pq_iters = int(pq_iters)
         self.pq_oversample = max(1, int(pq_oversample))
@@ -259,7 +256,11 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
         n_lists = self.n_lists or 1 << (auto.bit_length() - 1)
         n_lists = min(n_lists, n_live)
         d_probe = self.probe_dim or self.sched.d_max
-        db_live = db[jnp.asarray(live)][:, :d_probe].astype(jnp.float32)
+
+        def live_rows(sel):
+            # probe-space rows of the live ids ``sel``, gathered per use: a
+            # copy of every live row would double the store on the device
+            return db[jnp.asarray(sel), :d_probe].astype(jnp.float32)
 
         # Train the quantizer on a bounded sample (assignment covers all
         # rows below): k-means holds a (rows, n_lists) matrix per iteration.
@@ -267,11 +268,12 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
         if n_live > self.train_rows:
             sample = np.sort(rng.choice(n_live, self.train_rows,
                                         replace=False))
-            train = db_live[jnp.asarray(sample)]
+            train = live_rows(live[sample])
         else:
-            train = db_live
+            train = live_rows(live)
         cents = kmeans(train, n_lists, n_iter=self.kmeans_iters,
                        key=jax.random.PRNGKey(self.seed))
+        del train
         # centroid norms are probe-time constants: cache them in the state
         # so no search call recomputes them
         cent_sq = jnp.sum(cents.astype(jnp.float32) ** 2, axis=-1)
@@ -283,7 +285,7 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
         score_fn = T._METRICS[self.metric]
         neg_parts, choice_parts = [], []
         for lo in range(0, n_live, self.assign_block):
-            blk = db_live[lo: lo + self.assign_block]
+            blk = live_rows(live[lo: lo + self.assign_block])
             neg_b, choices_b = jax.lax.top_k(-score_fn(blk, cents, cent_sq), m)
             # keep tiles on device: converting inside the loop would sync
             # per tile and serialize dispatch against compute
@@ -297,7 +299,15 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
             cap = max(1, int(math.ceil(
                 self.balance_factor * n_live / n_lists)))
             order = np.argsort(-neg0)               # confident rows first
-            assign = balanced_assign(choices, order, n_lists, cap)
+
+            def rank_rest(rows):
+                return np.concatenate([
+                    np.asarray(jnp.argsort(score_fn(
+                        live_rows(live[rows[lo: lo + self.assign_block]]),
+                        cents, cent_sq), axis=1))
+                    for lo in range(0, rows.size, self.assign_block)])
+
+            assign = balanced_assign(choices, order, n_lists, cap, rank_rest)
 
         # Dense -1-padded table of *global* doc ids via the shared packing
         # path; append_spare slots stay free for incremental absorption, and
@@ -500,7 +510,6 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
                 valid=valid, sq_prefix=sq_prefix, index_dims=self.dims,
                 extra_cand=tail, metric=self.metric,
                 cent_sq=state.data["cent_sq"], pack=state.data["pack"],
-                merge=self.kernel_merge,
                 pq_oversample=pq_os,
                 interpret=self._interpret(),
             )
@@ -570,7 +579,6 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
                     valid=valid, sq_prefix=sq_prefix, index_dims=self.dims,
                     extra_cand=tail, metric=self.metric,
                     cent_sq=state.data["cent_sq"], pack=state.data["pack"],
-                    merge=self.kernel_merge,
                     pq_oversample=pq_os,
                     interpret=self._interpret(),
                     stage0_only=True,

@@ -78,7 +78,6 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
         encode_appends: bool = True,
         use_kernel="auto",
         kernel_block_m: int = 128,
-        kernel_merge: str = "sort",
         seed: int = 0,
     ):
         """Args beyond the shared churn config:
@@ -86,7 +85,7 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
         codec:          'int8' (per-dim symmetric codes) | 'pq' (product
                         quantization: pq_m uint8 codes/row + ADC tables).
         pq_m:           'pq' only: subspaces per stage-0 row (None: aim
-                        8-dim subspaces — `repro.core.pq.auto_pq_m`); must
+                        4-dim subspaces — `repro.core.pq.auto_pq_m`); must
                         divide the stage-0 dim.
         pq_codes:       'pq' only: centroids per subspace (<= 256).
         pq_iters:       'pq' only: k-means iterations per subspace.
@@ -105,7 +104,7 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
                         True forces it, interpret mode off-TPU; False: the
                         XLA ADC reference).  int8 stage 0 is a plain
                         matmul — XLA already lowers it well.
-        kernel_block_m / kernel_merge: kernel step rows / top-k merge.
+        kernel_block_m: code-slab rows per kernel step.
         """
         super().__init__(
             sched, metric=metric, block_n=block_n,
@@ -134,7 +133,6 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
         self.encode_appends = bool(encode_appends)
         self.use_kernel = use_kernel
         self.kernel_block_m = int(kernel_block_m)
-        self.kernel_merge = kernel_merge
         self.seed = int(seed)
         s0_dim = sched.stages[0].dim
         if codec == "pq":
@@ -284,8 +282,7 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
             )
             if self._kernel_enabled():
                 scores, ids = pq_progressive_search_kernel(
-                    q, idx, self.sched, merge=self.kernel_merge,
-                    block_m=self.kernel_block_m,
+                    q, idx, self.sched, block_m=self.kernel_block_m,
                     oversample=pq_os,
                     interpret=self._interpret(), **kw)
             else:
@@ -330,8 +327,7 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
             )
             if self._kernel_enabled():
                 scores, cand = pq_progressive_search_kernel(
-                    q, idx, self.sched, merge=self.kernel_merge,
-                    block_m=self.kernel_block_m,
+                    q, idx, self.sched, block_m=self.kernel_block_m,
                     oversample=pq_os,
                     interpret=self._interpret(), **kw)
             else:

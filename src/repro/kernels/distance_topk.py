@@ -19,16 +19,13 @@ row-major order and revisit scratch in place):
     sq_ref : (1, bn)    VMEM     scratch: best_s/best_i (bq, k)
 
 Per tile: ``scores = sq - 2 * q @ db^T`` on the MXU (f32 accumulate), then the
-tile's candidates are folded into the carry.  Two merge strategies:
+tile's candidates are folded into the carry by `merge_topk` and the carry
+is ordered once by `sort_topk` at the last tile.  Both are written with iota
+compares, ``jnp.where`` and lane reductions only: Mosaic lowers no
+``top_k``, gather or scatter inside a kernel.  The same two functions merge
+the running top-k of `repro.kernels.ivf_scan` and `repro.kernels.pq_scan`.
 
-* ``merge='sort'``   — concat (k + bn) columns, one ``lax.top_k``.  Fewer,
-  larger ops; relies on Mosaic's sort lowering.
-* ``merge='select'`` — k iterations of (argmin, mask).  Only min/where/iota —
-  lowers everywhere, and is the guaranteed path on older toolchains.
-
-Both are validated against `repro.kernels.ref.l2_topk_ref` in interpret mode
-(this container is CPU-only; real-TPU runs select the same code path with
-``interpret=False``).
+Validated against `repro.kernels.ref.l2_topk_ref` in interpret mode.
 """
 
 from __future__ import annotations
@@ -39,51 +36,82 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams, MemorySpace
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
-_NEG_INF = float("-inf")
 
+def merge_topk(best_s: Array, best_i: Array, s: Array, ids: Array
+               ) -> Tuple[Array, Array]:
+    """Fold a block of candidates into an unsorted running top-k.
 
-def _merge_topk_sort(cat_s: Array, cat_i: Array, k: int) -> Tuple[Array, Array]:
-    """Top-k smallest via one descending top_k on negated scores."""
-    neg, pos = jax.lax.top_k(-cat_s, k)
-    return -neg, jnp.take_along_axis(cat_i, pos, axis=1)
+    ``best_s``/``best_i`` (R, k) hold each row's k best (score, id) pairs in
+    no particular order, with (+inf, -1) in empty slots; ``s``/``ids``
+    (R, width) are the block's candidates.  Each round moves the block's
+    smallest remaining score (lowest column on ties) into the slot of the
+    row's current worst when it is strictly smaller, so ties keep what the
+    carry already holds.  The trip count is the largest per-row number of
+    block scores under the row's worst carried score: once the carry is
+    full, most blocks cost one compare and no rounds.
 
-
-def _merge_topk_select(cat_s: Array, cat_i: Array, k: int) -> Tuple[Array, Array]:
-    """Top-k smallest via k rounds of (min, argmin-mask).
-
-    O(k · width) VPU work, but only elementwise ops + reductions, which lower
-    on every Mosaic version.  Ties broken by lowest column index.
+    Only iota compares, ``jnp.where`` and lane reductions — no ``top_k``,
+    gather or scatter, which Mosaic does not lower.
     """
-    bq, width = cat_s.shape
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, width), 1)
+    r, k = best_s.shape
+    width = s.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (r, width), 1)
+    kcols = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
+    worst0 = jnp.max(best_s, axis=1, keepdims=True)
+    n_new = jnp.max(jnp.sum((s < worst0).astype(jnp.int32), axis=1))
+
+    def body(_, carry):
+        bs, bi, s = carry
+        m = jnp.min(s, axis=1, keepdims=True)                     # (r, 1)
+        first = jnp.min(jnp.where(s == m, cols, width), axis=1, keepdims=True)
+        hit = cols == first
+        m_id = jnp.sum(jnp.where(hit, ids, 0), axis=1, keepdims=True)
+        s = jnp.where(hit, jnp.inf, s)
+        worst = jnp.max(bs, axis=1, keepdims=True)
+        slot = jnp.min(jnp.where(bs == worst, kcols, k), axis=1, keepdims=True)
+        put = (kcols == slot) & (m < worst)
+        return jnp.where(put, m, bs), jnp.where(put, m_id, bi), s
+
+    best_s, best_i, _ = jax.lax.fori_loop(0, n_new, body, (best_s, best_i, s))
+    return best_s, best_i
+
+
+def sort_topk(best_s: Array, best_i: Array) -> Tuple[Array, Array]:
+    """Order a `merge_topk` carry ascending by score, ties by lower id.
+
+    k rounds of (min, first-id) extraction; slots still empty come out as
+    (+inf, -1).  Same lowering constraints as `merge_topk`.
+    """
+    r, k = best_s.shape
+    kcols = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
+    big = jnp.iinfo(jnp.int32).max
 
     def body(j, carry):
-        s, out_s, out_i = carry
-        m = jnp.min(s, axis=1, keepdims=True)                    # (bq, 1)
+        s, i, out_s, out_i = carry
+        m = jnp.min(s, axis=1, keepdims=True)
         is_min = s == m
-        # lowest column among the minima
-        first = jnp.min(jnp.where(is_min, cols, width), axis=1, keepdims=True)
-        hit = cols == first
-        out_s = out_s.at[:, j].set(m[:, 0])
-        out_i = out_i.at[:, j].set(
-            jnp.sum(jnp.where(hit, cat_i, 0), axis=1)
-        )
-        s = jnp.where(hit, jnp.inf, s)
-        return s, out_s, out_i
+        m_id = jnp.min(jnp.where(is_min, i, big), axis=1, keepdims=True)
+        first = jnp.min(jnp.where(is_min & (i == m_id), kcols, k),
+                        axis=1, keepdims=True)
+        taken = kcols == first
+        at_j = kcols == j
+        out_s = jnp.where(at_j, m, out_s)
+        out_i = jnp.where(at_j, jnp.where(m < jnp.inf, m_id, -1), out_i)
+        return (jnp.where(taken, jnp.inf, s), jnp.where(taken, big, i),
+                out_s, out_i)
 
-    out_s = jnp.zeros((bq, k), cat_s.dtype)
-    out_i = jnp.zeros((bq, k), cat_i.dtype)
-    _, out_s, out_i = jax.lax.fori_loop(0, k, body, (cat_s, out_s, out_i))
+    _, _, out_s, out_i = jax.lax.fori_loop(
+        0, k, body, (best_s, best_i, best_s, best_i))
     return out_s, out_i
 
 
 def _kernel(
     q_ref, db_ref, sq_ref, out_s_ref, out_i_ref, best_s, best_i,
-    *, k: int, bn: int, merge: str, n_valid: int,
+    *, k: int, bn: int, n_valid: int,
 ):
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -106,24 +134,18 @@ def _kernel(
     # Mask rows past the true db length (padding tile).
     scores = jnp.where(col < n_valid, scores, jnp.inf)
 
-    cat_s = jnp.concatenate([best_s[...], scores], axis=1)
-    cat_i = jnp.concatenate([best_i[...], col], axis=1)
-    if merge == "sort":
-        new_s, new_i = _merge_topk_sort(cat_s, cat_i, k)
-    else:
-        new_s, new_i = _merge_topk_select(cat_s, cat_i, k)
+    new_s, new_i = merge_topk(best_s[...], best_i[...], scores, col)
     best_s[...] = new_s
     best_i[...] = new_i
 
     @pl.when(j == nj - 1)
     def _flush():
-        out_s_ref[...] = best_s[...]
-        out_i_ref[...] = best_i[...]
+        out_s_ref[...], out_i_ref[...] = sort_topk(best_s[...], best_i[...])
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "block_q", "block_n", "merge", "interpret"),
+    static_argnames=("k", "block_q", "block_n", "interpret"),
 )
 def l2_topk(
     q: Array,
@@ -133,7 +155,6 @@ def l2_topk(
     db_sq: Optional[Array] = None,
     block_q: int = 256,
     block_n: int = 512,
-    merge: str = "sort",
     interpret: bool = False,
 ) -> Tuple[Array, Array]:
     """Fused distance+top-k scan of ``db`` for each row of ``q``.
@@ -145,7 +166,6 @@ def l2_topk(
       db_sq:  optional (N,) precomputed squared norms.
       block_q/block_n: VMEM tile sizes.  ``d * (block_q + block_n) * 4`` bytes
         plus the (block_q, block_n) score tile must fit VMEM (~16 MB/core).
-      merge:  'sort' | 'select' (see module docstring).
       interpret: run the kernel in interpret mode (CPU validation).
 
     Returns:
@@ -171,7 +191,7 @@ def l2_topk(
 
     grid = (q.shape[0] // block_q, db.shape[0] // block_n)
     kernel = functools.partial(
-        _kernel, k=k, bn=block_n, merge=merge, n_valid=n
+        _kernel, k=k, bn=block_n, n_valid=n
     )
     out_s, out_i = pl.pallas_call(
         kernel,
@@ -190,10 +210,10 @@ def l2_topk(
             jax.ShapeDtypeStruct((q.shape[0], k), jnp.int32),
         ],
         scratch_shapes=[
-            MemorySpace.VMEM((block_q, k), jnp.float32),
-            MemorySpace.VMEM((block_q, k), jnp.int32),
+            pltpu.MemorySpace.VMEM((block_q, k), jnp.float32),
+            pltpu.MemorySpace.VMEM((block_q, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import MemorySpace
 
 Array = jax.Array
 
@@ -110,10 +109,10 @@ def embedding_bag(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bp // block_b,),
-            in_specs=[pl.BlockSpec(memory_space=MemorySpace.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
             out_specs=pl.BlockSpec((block_b, d), lambda g, idx: (g, 0)),
             scratch_shapes=[
-                MemorySpace.VMEM((2, d), jnp.float32),
+                pltpu.MemorySpace.VMEM((2, d), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
         ),

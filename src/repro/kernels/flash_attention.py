@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams, MemorySpace
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
@@ -162,11 +162,11 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, bq, dh), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hq, sqp, dh), q.dtype),
         scratch_shapes=[
-            MemorySpace.VMEM((bq, dh), jnp.float32),
-            MemorySpace.VMEM((bq, 1), jnp.float32),
-            MemorySpace.VMEM((bq, 1), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, dh), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, 1), jnp.float32),
+            pltpu.MemorySpace.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

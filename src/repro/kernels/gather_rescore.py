@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import MemorySpace
 
 Array = jax.Array
 
@@ -132,11 +131,11 @@ def gather_rescore(
             grid=(nq,),
             in_specs=[
                 pl.BlockSpec((1, d), lambda i, cand: (i, 0)),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             ],
             out_specs=pl.BlockSpec((1, c_total), lambda i, cand: (i, 0)),
             scratch_shapes=[
-                MemorySpace.VMEM((2, block_c, d), jnp.float32),
+                pltpu.MemorySpace.VMEM((2, block_c, d), jnp.float32),
                 pltpu.SemaphoreType.DMA((2,)),
             ],
         ),

@@ -21,8 +21,8 @@ collapses them into **one streaming read of the probed lists' member rows**:
   with padding (``-1`` ids) and tombstoned rows masked to +inf in-kernel via
   the caller-masked id table.
 * A running top-k rides in VMEM scratch across the sequential
-  (probe × chunk) grid axis, reusing `distance_topk`'s ``sort``/``select``
-  merge strategies — only the final (Q, k) result ever reaches HBM.
+  (probe × chunk) grid axis, merged by `distance_topk.merge_topk` — only
+  the final (Q, k) result ever reaches HBM.
 
 An **int8 member-block variant** composes with `repro.core.quant`: member
 slabs are stored as per-dimension-scaled int8 codes (4× less stage-0 HBM
@@ -32,8 +32,8 @@ packed norms are the dequantized ones — so the quantized and IVF backends
 stop being either/or.
 
 Validated against `repro.kernels.ref.ivf_scan_ref` and the XLA
-`ivf_progressive_search_sched` path in interpret mode (CPU container); the
-same code targets real TPUs with ``interpret=False``.
+`ivf_progressive_search_sched` path in interpret mode, and compiled for a
+TPU v5e in `tests/test_tpu_compile.py`.
 """
 
 from __future__ import annotations
@@ -49,8 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
-from repro.kernels.compat import CompilerParams, MemorySpace
-from repro.kernels.distance_topk import _merge_topk_select, _merge_topk_sort
+from repro.kernels.distance_topk import merge_topk, sort_topk
 
 Array = jax.Array
 
@@ -181,7 +180,7 @@ def update_pack(pack: Dict, db: Array, ids, dests) -> Dict:
 
 def _kernel(
     probe_ref, q_ref, rows_ref, sq_ref, ids_ref, out_s_ref, out_i_ref,
-    best_s, best_i, *, k: int, merge: str, cast: str,
+    best_s, best_i,
 ):
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -192,84 +191,82 @@ def _kernel(
         best_i[...] = jnp.full_like(best_i, -1)
 
     q = q_ref[...]                                     # (1, d0) f32
-    rows = rows_ref[...]                               # (bm, d0)
-    # int8 slabs matmul through bf16 (the int8 path of core.quant); f32
-    # slabs pass through untouched
-    rows = rows.astype(jnp.bfloat16 if cast == "int8" else jnp.float32)
+    # int8 slabs widen in VMEM (exactly): HBM traffic stays one byte per dim
+    rows = rows_ref[...].astype(jnp.float32)           # (bm, d0)
     ip = jax.lax.dot_general(
         q, rows, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                  # (1, bm)
+    ids = ids_ref[...]
     scores = sq_ref[...] - 2.0 * ip
     # -1 ids are list padding or tombstoned rows: unreturnable
-    scores = jnp.where(ids_ref[...] >= 0, scores, jnp.inf)
-
-    cat_s = jnp.concatenate([best_s[...], scores], axis=1)
-    cat_i = jnp.concatenate([best_i[...], ids_ref[...]], axis=1)
-    if merge == "sort":
-        new_s, new_i = _merge_topk_sort(cat_s, cat_i, k)
-    else:
-        new_s, new_i = _merge_topk_select(cat_s, cat_i, k)
-    best_s[...] = new_s
-    best_i[...] = new_i
+    scores = jnp.where(ids >= 0, scores, jnp.inf)
+    best_s[...], best_i[...] = merge_topk(best_s[...], best_i[...],
+                                          scores, ids)
 
     @pl.when(j == nj - 1)
     def _flush():
-        out_s_ref[...] = best_s[...]
-        out_i_ref[...] = best_i[...]
+        out_s_ref[...], out_i_ref[...] = sort_topk(best_s[...], best_i[...])
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "dim", "max_len", "block_m", "dtype", "merge",
-                     "interpret"),
+    static_argnames=("k", "dim", "max_len", "block_m", "interpret"),
 )
 def _ivf_scan_call(
-    q, probe, rows, sq, member_ids, *, k, dim, max_len, block_m, dtype,
-    merge, interpret,
+    q, probe, rows, sq, member_ids, *, k, dim, max_len, block_m, interpret,
 ):
     nq = q.shape[0]
+    n_lists = sq.shape[0]
     n_probe = probe.shape[1]
     nc = max_len // block_m
     nj = n_probe * nc
+    sqz = pl.squeezed
 
+    # Per-query and per-list blocks are one row high.  Mosaic tiles the
+    # last two block dims by (8, 128) unless they span the array, so each
+    # such array carries a unit middle axis and the BlockSpec squeezes its
+    # leading one: the kernel sees (1, n) blocks of a (1, n)-wide array.
     def rows_idx(i, j, probe):
         return (probe[i, j // nc] * nc + j % nc, 0)
 
     def list_idx(i, j, probe):
-        return (probe[i, j // nc], j % nc)
+        return (probe[i, j // nc], 0, j % nc)
 
-    kern = functools.partial(_kernel, k=k, merge=merge, cast=dtype)
+    def query_idx(i, j, probe):
+        return (i, 0, 0)
+
     out_s, out_i = pl.pallas_call(
-        kern,
+        _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(nq, nj),
             in_specs=[
-                pl.BlockSpec((1, dim), lambda i, j, probe: (i, 0)),
+                pl.BlockSpec((sqz, 1, dim), query_idx),
                 pl.BlockSpec((block_m, dim), rows_idx),
-                pl.BlockSpec((1, block_m), list_idx),
-                pl.BlockSpec((1, block_m), list_idx),
+                pl.BlockSpec((sqz, 1, block_m), list_idx),
+                pl.BlockSpec((sqz, 1, block_m), list_idx),
             ],
             out_specs=[
-                pl.BlockSpec((1, k), lambda i, j, probe: (i, 0)),
-                pl.BlockSpec((1, k), lambda i, j, probe: (i, 0)),
+                pl.BlockSpec((sqz, 1, k), query_idx),
+                pl.BlockSpec((sqz, 1, k), query_idx),
             ],
             scratch_shapes=[
-                MemorySpace.VMEM((1, k), jnp.float32),
-                MemorySpace.VMEM((1, k), jnp.int32),
+                pltpu.MemorySpace.VMEM((1, k), jnp.float32),
+                pltpu.MemorySpace.VMEM((1, k), jnp.int32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(probe, q, rows, sq, member_ids)
-    return out_s, out_i
+    )(probe, q[:, None, :], rows, sq.reshape(n_lists, 1, max_len),
+      member_ids.reshape(n_lists, 1, max_len))
+    return out_s[:, 0], out_i[:, 0]
 
 
 def ivf_scan_topk(
@@ -279,7 +276,6 @@ def ivf_scan_topk(
     pack: Dict,
     *,
     k: int,
-    merge: str = "sort",
     interpret: bool = False,
 ) -> Tuple[Array, Array]:
     """Fused stage-0 scan: score every probed list's members, keep the best k.
@@ -296,15 +292,12 @@ def ivf_scan_topk(
                   snapshot and are not consulted for liveness).
       pack:       `pack_ivf_lists` output (member slabs at stage-0 dim).
       k:          neighbours kept (static).
-      merge:      'sort' | 'select' (see `distance_topk`).
       interpret:  interpret mode for CPU validation.
 
     Returns:
       ((Q, k) float32 rank-equivalent L2 scores ascending, +inf at empty
       slots; (Q, k) int32 global doc ids, -1 at empty slots).
     """
-    if merge not in ("sort", "select"):
-        raise ValueError(f"merge must be sort|select, got {merge!r}")
     if pack["dtype"] == "pq":
         raise ValueError(
             "pq packs are scanned by repro.kernels.pq_scan.pq_ivf_scan_topk "
@@ -324,8 +317,7 @@ def ivf_scan_topk(
                              constant_values=-1)
     return _ivf_scan_call(
         qd, probe.astype(jnp.int32), pack["rows"], pack["sq"], member_ids,
-        k=k, dim=d0, max_len=max_len, block_m=bm, dtype=pack["dtype"],
-        merge=merge, interpret=interpret,
+        k=k, dim=d0, max_len=max_len, block_m=bm, interpret=interpret,
     )
 
 

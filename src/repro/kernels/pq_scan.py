@@ -10,13 +10,13 @@ VMEM** for the whole scan and streams only the uint8 code slabs:
   auto-double-buffered block pipeline as `ivf_scan` — M bytes per row, the
   4–8× compression step past the int8 member slabs.
 * In-VMEM table lookup is a **one-hot contraction**: TPUs have no fast
-  VMEM gather, but ``codes == iota(C)`` builds a (block_m, M·C) one-hot
-  that contracts with the flattened LUT on the MXU — a (1, M·C) ×
-  (block_m, M·C) matmul whose result IS the ADC score row.
+  VMEM gather, but ``codes[:, m] == iota(C)`` builds a (block_m, C)
+  one-hot per subspace that contracts with LUT row m on the MXU; the M
+  (1, block_m) products sum to the ADC score row.
 * Padding and tombstones are masked in-kernel via the caller-masked id
   table (-1 ids score +inf), and the running top-k rides in VMEM scratch
-  (reusing `distance_topk`'s sort/select merges); only the final (Q, k)
-  result ever reaches HBM.
+  (merged by `distance_topk.merge_topk`); only the final (Q, k) result
+  ever reaches HBM.
 
 Two grid shapes share the kernel body:
 
@@ -28,8 +28,8 @@ Two grid shapes share the kernel body:
   ``IVFProgressiveBackend(stage0_dtype='pq')``.
 
 Validated against `repro.kernels.ref.pq_scan_ref` / `pq_ivf_scan_ref` and
-the XLA `pq_progressive_search` path in interpret mode (CPU container);
-the same code targets real TPUs with ``interpret=False``.
+the XLA `pq_progressive_search` path in interpret mode, and compiled for a
+TPU v5e in `tests/test_tpu_compile.py`.
 """
 
 from __future__ import annotations
@@ -42,14 +42,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams, MemorySpace
-from repro.kernels.distance_topk import _merge_topk_select, _merge_topk_sort
+from repro.kernels.distance_topk import merge_topk, sort_topk
 
 Array = jax.Array
 
 
 def _pq_body(lut_ref, codes_ref, ids_ref, out_s_ref, out_i_ref,
-             best_s, best_i, *, k: int, merge: str):
+             best_s, best_i):
     """Score one (block_m, M) code slab against the resident LUT."""
     j = pl.program_id(1)
     nj = pl.num_programs(1)
@@ -59,72 +58,73 @@ def _pq_body(lut_ref, codes_ref, ids_ref, out_s_ref, out_i_ref,
         best_s[...] = jnp.full_like(best_s, jnp.inf)
         best_i[...] = jnp.full_like(best_i, -1)
 
-    lut = lut_ref[...]                               # (1, M, C) f32
-    m, c = lut.shape[1], lut.shape[2]
+    lut = lut_ref[...]                               # (M, C) f32
+    m, c = lut.shape
     codes = codes_ref[...].astype(jnp.int32)         # (bm, M)
     bm = codes.shape[0]
-    # one-hot contraction: the TPU-native LUT gather. hot[r, m, c] selects
-    # row r's code in subspace m; contracting (M, C) jointly against the
-    # flattened LUT sums the M table entries in one MXU pass.
-    hot = (codes[:, :, None]
-           == jax.lax.broadcasted_iota(jnp.int32, (1, 1, c), 2))
-    scores = jax.lax.dot_general(
-        lut.reshape(1, m * c),
-        hot.astype(jnp.float32).reshape(bm, m * c),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                # (1, bm)
+    # one-hot contraction: the TPU-native LUT gather.  For subspace m,
+    # hot[r, c] selects row r's code; contracting it against the LUT row
+    # on the MXU yields that subspace's table entry for every row, and the
+    # M partial rows sum to the ADC score row.
+    entry = jax.lax.broadcasted_iota(jnp.int32, (bm, c), 1)
+    scores = jnp.zeros((1, bm), jnp.float32)
+    for sub in range(m):
+        hot = (codes[:, sub:sub + 1] == entry).astype(jnp.float32)
+        scores = scores + jax.lax.dot_general(
+            lut[sub:sub + 1, :], hot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                            # (1, bm)
+    ids = ids_ref[...]
     # -1 ids are padding or tombstoned rows: unreturnable
-    scores = jnp.where(ids_ref[...] >= 0, scores, jnp.inf)
-
-    cat_s = jnp.concatenate([best_s[...], scores], axis=1)
-    cat_i = jnp.concatenate([best_i[...], ids_ref[...]], axis=1)
-    if merge == "sort":
-        new_s, new_i = _merge_topk_sort(cat_s, cat_i, k)
-    else:
-        new_s, new_i = _merge_topk_select(cat_s, cat_i, k)
-    best_s[...] = new_s
-    best_i[...] = new_i
+    scores = jnp.where(ids >= 0, scores, jnp.inf)
+    best_s[...], best_i[...] = merge_topk(best_s[...], best_i[...],
+                                          scores, ids)
 
     @pl.when(j == nj - 1)
     def _flush():
-        out_s_ref[...] = best_s[...]
-        out_i_ref[...] = best_i[...]
+        out_s_ref[...], out_i_ref[...] = sort_topk(best_s[...], best_i[...])
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k", "block_m", "merge", "interpret"))
-def _pq_scan_call(lut, codes, ids, *, k, block_m, merge, interpret):
-    nq, m, c = lut.shape
-    nj = codes.shape[0] // block_m
-
-    kern = functools.partial(_pq_body, k=k, merge=merge)
+def _pq_call(body, grid_spec, nq, k, interpret, *args):
+    """Shared pallas_call of both grid shapes: (Q, 1, k) outputs, squeezed
+    per query (see `repro.kernels.ivf_scan._ivf_scan_call`)."""
     out_s, out_i = pl.pallas_call(
-        kern,
-        grid=(nq, nj),
-        in_specs=[
-            pl.BlockSpec((1, m, c), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((block_m, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, block_m), lambda i, j: (0, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-        ],
-        scratch_shapes=[
-            MemorySpace.VMEM((1, k), jnp.float32),
-            MemorySpace.VMEM((1, k), jnp.int32),
-        ],
+        body,
+        grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
+            jax.ShapeDtypeStruct((nq, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((nq, 1, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(lut, codes, ids)
-    return out_s, out_i
+    )(*args)
+    return out_s[:, 0], out_i[:, 0]
+
+
+def _scratch(k):
+    return [pltpu.MemorySpace.VMEM((1, k), jnp.float32),
+            pltpu.MemorySpace.VMEM((1, k), jnp.int32)]
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block_m", "interpret"))
+def _pq_scan_call(lut, codes, ids, *, k, block_m, interpret):
+    nq, m, c = lut.shape
+    nj = codes.shape[0] // block_m
+    sqz = pl.squeezed
+    out_spec = pl.BlockSpec((sqz, 1, k), lambda i, j: (i, 0, 0))
+    grid_spec = pl.GridSpec(
+        grid=(nq, nj),
+        in_specs=[
+            pl.BlockSpec((sqz, m, c), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((block_m, m), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, block_m), lambda i, j: (0, j)),
+        ],
+        out_specs=[out_spec, out_spec],
+        scratch_shapes=_scratch(k),
+    )
+    return _pq_call(_pq_body, grid_spec, nq, k, interpret, lut, codes, ids)
 
 
 def pq_scan_topk(
@@ -134,7 +134,6 @@ def pq_scan_topk(
     *,
     k: int,
     block_m: int = 128,
-    merge: str = "sort",
     interpret: bool = False,
 ) -> Tuple[Array, Array]:
     """Fused flat ADC scan: score every coded row, keep the best k.
@@ -146,15 +145,12 @@ def pq_scan_topk(
                  already masked to -1 (tombstones, rows past the coded
                  prefix); live rows carry their own index.
       k:         neighbours kept (static).
-      merge:     'sort' | 'select' (see `distance_topk`).
       interpret: interpret mode for CPU validation.
 
     Returns:
       ((Q, k) float32 rank-equivalent ADC scores ascending, +inf at empty
       slots; (Q, k) int32 global doc ids, -1 at empty slots).
     """
-    if merge not in ("sort", "select"):
-        raise ValueError(f"merge must be sort|select, got {merge!r}")
     nq = lut.shape[0]
     if nq == 0:
         return (jnp.zeros((0, k), jnp.float32), jnp.zeros((0, k), jnp.int32))
@@ -166,59 +162,43 @@ def pq_scan_topk(
         ids = jnp.pad(ids, (0, pad), constant_values=-1)
     return _pq_scan_call(
         lut.astype(jnp.float32), codes, ids[None, :].astype(jnp.int32),
-        k=k, block_m=bm, merge=merge, interpret=interpret)
+        k=k, block_m=bm, interpret=interpret)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("k", "max_len", "block_m", "merge", "interpret"))
+    jax.jit, static_argnames=("k", "max_len", "block_m", "interpret"))
 def _pq_ivf_call(lut, probe, codes, member_ids, *, k, max_len, block_m,
-                 merge, interpret):
+                 interpret):
     nq, m, c = lut.shape
+    n_lists = member_ids.shape[0]
     n_probe = probe.shape[1]
     nc = max_len // block_m
     nj = n_probe * nc
+    sqz = pl.squeezed
 
     def codes_idx(i, j, probe):
         return (probe[i, j // nc] * nc + j % nc, 0)
 
     def list_idx(i, j, probe):
-        return (probe[i, j // nc], j % nc)
-
-    body = functools.partial(_pq_body, k=k, merge=merge)
+        return (probe[i, j // nc], 0, j % nc)
 
     def kern(probe_ref, *args):
-        body(*args)
+        _pq_body(*args)
 
-    out_s, out_i = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(nq, nj),
-            in_specs=[
-                pl.BlockSpec((1, m, c), lambda i, j, probe: (i, 0, 0)),
-                pl.BlockSpec((block_m, m), codes_idx),
-                pl.BlockSpec((1, block_m), list_idx),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, k), lambda i, j, probe: (i, 0)),
-                pl.BlockSpec((1, k), lambda i, j, probe: (i, 0)),
-            ],
-            scratch_shapes=[
-                MemorySpace.VMEM((1, k), jnp.float32),
-                MemorySpace.VMEM((1, k), jnp.int32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq, k), jnp.int32),
+    out_spec = pl.BlockSpec((sqz, 1, k), lambda i, j, probe: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nq, nj),
+        in_specs=[
+            pl.BlockSpec((sqz, m, c), lambda i, j, probe: (i, 0, 0)),
+            pl.BlockSpec((block_m, m), codes_idx),
+            pl.BlockSpec((sqz, 1, block_m), list_idx),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(probe, lut, codes, member_ids)
-    return out_s, out_i
+        out_specs=[out_spec, out_spec],
+        scratch_shapes=_scratch(k),
+    )
+    return _pq_call(kern, grid_spec, nq, k, interpret, probe, lut, codes,
+                    member_ids.reshape(n_lists, 1, max_len))
 
 
 def pq_ivf_scan_topk(
@@ -228,7 +208,6 @@ def pq_ivf_scan_topk(
     pack: Dict,
     *,
     k: int,
-    merge: str = "sort",
     interpret: bool = False,
     lut: Optional[Array] = None,
 ) -> Tuple[Array, Array]:
@@ -248,7 +227,6 @@ def pq_ivf_scan_topk(
                   slot pre-masked to -1 (padding AND tombstones).
       pack:       `pack_ivf_lists(..., dtype='pq')` output.
       k:          neighbours kept (static).
-      merge:      'sort' | 'select'.
       interpret:  interpret mode for CPU validation.
       lut:        optional precomputed (Q, M, C) ADC tables.
 
@@ -258,8 +236,6 @@ def pq_ivf_scan_topk(
     """
     from repro.core.pq import pq_lut
 
-    if merge not in ("sort", "select"):
-        raise ValueError(f"merge must be sort|select, got {merge!r}")
     if pack["dtype"] != "pq":
         raise ValueError(
             f"pq_ivf_scan_topk needs a dtype='pq' pack, got "
@@ -277,8 +253,7 @@ def pq_ivf_scan_topk(
                              constant_values=-1)
     return _pq_ivf_call(
         lut.astype(jnp.float32), probe.astype(jnp.int32), pack["rows"],
-        member_ids, k=k, max_len=max_len, block_m=bm, merge=merge,
-        interpret=interpret)
+        member_ids, k=k, max_len=max_len, block_m=bm, interpret=interpret)
 
 
 def flat_stage0_bytes_model(

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import MemorySpace
 
 Array = jax.Array
 
@@ -129,13 +128,13 @@ def sorted_segment_sum(
             num_scalar_prefetch=1,
             grid=(num_segments // block_n,),
             in_specs=[
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
-                pl.BlockSpec(memory_space=MemorySpace.ANY),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+                pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             ],
             out_specs=pl.BlockSpec((block_n, d), lambda g, ip: (g, 0)),
             scratch_shapes=[
-                MemorySpace.VMEM((2, edge_chunk, d), data.dtype),
-                MemorySpace.VMEM((2, edge_chunk, 1), jnp.int32),
+                pltpu.MemorySpace.VMEM((2, edge_chunk, d), data.dtype),
+                pltpu.MemorySpace.VMEM((2, edge_chunk, 1), jnp.int32),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),

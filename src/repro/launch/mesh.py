@@ -16,22 +16,17 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """jax-version-portable ``make_mesh``: jax >= 0.5 takes explicit
-    axis_types; 0.4.x has no AxisType (all axes behave as Auto there, which
-    is what we want on both).  Public because tests and tools need the same
-    shim."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: shardings follow the
+    annotations (``jax.make_mesh`` alone makes Explicit axes)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_elastic_mesh(n_model: int = 0):
@@ -47,4 +42,4 @@ def make_elastic_mesh(n_model: int = 0):
     while n_model > 1 and n % n_model:
         n_model //= 2
     n_data = n // n_model
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import LMConfig
 from repro.engine import EngineConfig, EngineDriver, RetrievalEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm as LM
 from repro.rag import RAGPipeline
 from repro.rag.pipeline import mean_pool_embedder
@@ -132,6 +133,10 @@ def serve_http(args) -> None:
     config = EngineConfig.from_flags(args, d_emb=args.d_emb,
                                      capacity=max(args.docs, 1024))
     engine = RetrievalEngine(config=config)
+    dev = jax.devices()[0]
+    print(f"[device] platform={dev.platform} "
+          f"kind={dev.device_kind.replace(' ', '_')} "
+          f"count={len(jax.devices())}")
     replication = None
     applier = None
     if role == "follower":
@@ -384,6 +389,7 @@ def closed_loop(args) -> None:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--docs", type=int, default=2000)
     ap.add_argument("--requests", type=int, default=64)

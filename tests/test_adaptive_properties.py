@@ -26,8 +26,11 @@ _OPS = st.lists(
 
 # One driver per backend shared across examples (construction + warm
 # compilation dominate; the invariant is a safety property over any
-# starting state, so carried-over corpus contents are fine).
+# starting state, so carried-over corpus contents are fine).  The cache
+# carries over with the driver, so the hot query's last uncached serve
+# (ids, stamp right after it) carries over with it.
 _DRIVERS = {}
+_LAST_HOT = {}
 
 
 def _shared_driver(backend):
@@ -44,6 +47,7 @@ def _stop_shared_drivers():
     yield
     while _DRIVERS:
         _DRIVERS.popitem()[1].stop()
+    _LAST_HOT.clear()
 
 
 class TestCacheNeverStale:
@@ -58,8 +62,8 @@ class TestCacheNeverStale:
         cached serve must imply zero stamp movement since its insert."""
         drv = _shared_driver(backend)
         eng = drv.engine
-        last_ids = None       # ids from the last uncached hot serve
-        last_stamp = None     # stamp right after that serve
+        # ids from the last uncached hot serve and the stamp right after it
+        last_ids, last_stamp = _LAST_HOT.get(backend, (None, None))
         for op in ops:
             if op == "add":
                 eng.add_docs(RNG.normal(size=(2, D)).astype(np.float32))
@@ -82,3 +86,4 @@ class TestCacheNeverStale:
                 else:
                     last_ids = r.doc_ids
                     last_stamp = eng.cache_stamp()
+                    _LAST_HOT[backend] = (last_ids, last_stamp)

@@ -589,6 +589,25 @@ class TestBalancedAssign:
         assign = balanced_assign(choices, np.arange(6), n_lists=3, cap=2)
         assert (np.bincount(assign, minlength=3) == 2).all()
 
+    def test_overflow_rows_take_nearest_list_with_room(self):
+        # every row's one choice is list 0; beyond it, rows rank list 3
+        # above lists 1 and 2, so the overflow fills list 3 first and only
+        # then list 1 (never the lowest-indexed free list first)
+        choices = np.zeros((6, 1), np.int64)
+        full = np.tile([0, 3, 1, 2], (6, 1))
+        seen = []
+
+        def rank_rest(rows):
+            seen.append(rows.copy())
+            return full[rows]
+
+        assign = balanced_assign(choices, np.arange(6)[::-1], n_lists=4,
+                                 cap=2, rank_rest=rank_rest)
+        np.testing.assert_array_equal(assign, [1, 1, 3, 3, 0, 0])
+        # asked once, for the displaced rows in confidence order
+        np.testing.assert_array_equal(seen[0], [3, 2, 1, 0])
+        assert len(seen) == 1
+
     def test_impossible_cap_raises(self):
         with pytest.raises(ValueError):
             balanced_assign(np.zeros((5, 1), np.int64), np.arange(5),

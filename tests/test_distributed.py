@@ -36,8 +36,8 @@ def test_sharded_search_matches_single_device():
         q = db[gt] + 0.05 * rng.normal(size=(Q, D)).astype(np.float32)
         sched = make_schedule(16, 128, 16)
         idx = build_index(db, stage_dims(sched))
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ('data',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ('data',))
         sg, cg = sharded_progressive_search(
             mesh, jnp.asarray(q), jnp.asarray(db), sched,
             sq_prefix=idx['sq_prefix'], index_dims=stage_dims(sched),
@@ -75,8 +75,8 @@ def test_staged_search_matches_regular():
         gt = rng.choice(N, Q, replace=False)
         q = db[gt] + 0.2 * scales * rng.normal(size=(Q, D)).astype(np.float32)
         sched = make_schedule(32, 128, 32)
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ('data',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ('data',))
         db0 = jnp.asarray(db[:, :32], jnp.bfloat16)
         sqp = jnp.sum(jnp.asarray(db[:, :32])**2, axis=1, keepdims=True)
         fn = build_sharded_search_staged(mesh, sched, N)
@@ -103,8 +103,8 @@ def test_moe_ep_matches_single_device():
         p = moe_init(key, 64, cfg, 'swiglu', jnp.float32)
         x = jax.random.normal(key, (4, 16, 64))
         y_ref, _ = moe_apply(p, x, cfg, 'swiglu')
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ('data', 'model'))
         ctx = make_ctx(mesh)
         with mesh:
             y_ep, _ = jax.jit(
@@ -132,8 +132,8 @@ def test_lm_train_step_lowers_on_2d_mesh():
         from repro.optim.adamw import opt_state_logical
 
         cfg = get_arch('mistral-nemo-12b').SMOKE_CONFIG
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ('data', 'model'))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ('data', 'model'))
         ctx = make_ctx(mesh)
         params = jax.eval_shape(lambda: LM.init_lm(jax.random.PRNGKey(0), cfg))
         opt = jax.eval_shape(lambda: adamw_init(params))

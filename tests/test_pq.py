@@ -81,10 +81,12 @@ class TestCodec:
         assert rel < 0.05
 
     def test_auto_m(self):
-        assert auto_pq_m(64) == 8
-        assert auto_pq_m(16) == 2
-        assert auto_pq_m(8) == 1        # dsub stays >= 8 when small
-        assert auto_pq_m(12) == 1       # indivisible: single subspace
+        assert auto_pq_m(128) == 32     # the paper's stage-0 width
+        assert auto_pq_m(64) == 16
+        assert auto_pq_m(12) == 3
+        assert auto_pq_m(8) == 2
+        assert auto_pq_m(4) == 1        # dsub stays >= 4 when small
+        assert auto_pq_m(10) == 1       # indivisible: single subspace
 
     def test_indivisible_m_raises(self):
         with pytest.raises(ValueError, match="not divisible"):
