@@ -1,0 +1,8 @@
+"""Share of the traced part of the window in which no operation ran on the
+chip: 100 x (1 - union of the device's op intervals / traced seconds)."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)
