@@ -238,8 +238,7 @@ def ivf_progressive_search(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sched", "n_probe", "index_dims", "metric",
-                             "stage0_only")
+    jax.jit, static_argnames=("sched", "n_probe", "index_dims", "metric")
 )
 def ivf_progressive_search_sched(
     q: Array,
@@ -255,7 +254,6 @@ def ivf_progressive_search_sched(
     extra_cand: Optional[Array] = None,
     metric: str = "l2",
     cent_sq: Optional[Array] = None,
-    stage0_only: bool = False,
 ) -> Tuple[Array, Array]:
     """Full progressive schedule with IVF probing replacing the stage-0 scan.
 
@@ -276,31 +274,38 @@ def ivf_progressive_search_sched(
       cent_sq:    optional (n_lists,) precomputed centroid squared norms —
                   built backends cache these so probing doesn't recompute
                   them per search call.
+
+    Named scopes: ``stage0`` (``stage0/probe``: centroid scores and top-k;
+    then the member gather and the stage-0 scoring of the members) and
+    ``rescore`` (the ladder's later stages).
     """
     from repro.core.progressive import rescore_ladder
 
     s0 = sched.stages[0]
     score_fn = T._METRICS[metric]
 
-    d_probe = centroids.shape[1]
-    cs = score_fn(q[:, :d_probe], centroids, cent_sq)  # (Q, n_lists)
-    _, probe = jax.lax.top_k(-cs, min(n_probe, centroids.shape[0]))
-    cand = lists[probe].reshape(q.shape[0], -1)       # (Q, n_probe*max_len)
-    cand = T.inject_candidates(cand, extra_cand)
-    if cand.shape[1] < s0.k:
-        # top_k needs k <= C; -1 columns score +inf and change nothing
-        cand = jnp.pad(cand, ((0, 0), (0, s0.k - cand.shape[1])),
-                       constant_values=-1)
-    if stage0_only:
-        # fenced split: probing produced candidates but no scores — the
-        # ladder (ALL schedule stages, scores=None) finishes the search
-        return None, cand
-    # the probed members replace the stage-0 full scan; every schedule
-    # stage (stage 0 included) is now a rescore over them
+    with jax.named_scope("stage0"):
+        with jax.named_scope("probe"):
+            d_probe = centroids.shape[1]
+            cs = score_fn(q[:, :d_probe], centroids, cent_sq)  # (Q, n_lists)
+            _, probe = jax.lax.top_k(-cs, min(n_probe, centroids.shape[0]))
+        cand = lists[probe].reshape(q.shape[0], -1)   # (Q, n_probe*max_len)
+        cand = T.inject_candidates(cand, extra_cand)
+        if cand.shape[1] < s0.k:
+            # top_k needs k <= C; -1 columns score +inf and change nothing
+            cand = jnp.pad(cand, ((0, 0), (0, s0.k - cand.shape[1])),
+                           constant_values=-1)
+        # the probed members replace the stage-0 full scan: stage 0 is a
+        # rescore of them at its own dim, and every later stage follows
+        scores, cand = T.rescore_candidates(
+            q, db, cand, dim=s0.dim, k=s0.k,
+            db_sq_at_dim=_sq_col(sq_prefix, index_dims, s0.dim),
+            valid=valid, metric=metric,
+        )
     return rescore_ladder(
-        q, db, cand, sched.stages,
+        q, db, cand, sched.stages[1:],
         sq_prefix=sq_prefix, index_dims=index_dims,
-        valid=valid, metric=metric,
+        valid=valid, metric=metric, scores=scores,
     )
 
 
@@ -317,73 +322,81 @@ def _sq_col(sq_prefix, index_dims, dim: int):
 @functools.partial(
     jax.jit,
     static_argnames=("sched", "n_probe", "index_dims", "metric",
-                     "pack_meta", "pq_oversample", "interpret",
-                     "stage0_only"),
+                     "pack_meta", "pq_oversample", "interpret"),
 )
 def _kernel_search_jit(
     q, db, centroids, lists, pack_rows, pack_sq, pack_scale,
     pack_codebooks, pack_cent_sq,
     valid, sq_prefix, extra_cand, cent_sq, sched,
-    *, n_probe, index_dims, metric, pack_meta, pq_oversample,
-    interpret, stage0_only=False,
+    *, n_probe, index_dims, metric, pack_meta, pq_oversample, interpret,
 ):
+    """The fused IVF program.  Named scopes: ``stage0`` with ``probe``
+    (centroid scores and top-k), ``member_mask`` (the validity gather over
+    the member table), ``scan`` (the fused kernel) and ``tail`` (the
+    un-indexed rows); then ``rescore`` (the ladder)."""
     from repro.kernels.ivf_scan import ivf_scan_topk
     from repro.kernels.pq_scan import pq_ivf_scan_topk
     from repro.core.progressive import rescore_ladder
 
     s0 = sched.stages[0]
-    d_probe = centroids.shape[1]
-    cs = T._METRICS[metric](q[:, :d_probe], centroids, cent_sq)
-    _, probe = jax.lax.top_k(-cs, min(n_probe, centroids.shape[0]))
+    with jax.named_scope("stage0"):
+        with jax.named_scope("probe"):
+            d_probe = centroids.shape[1]
+            cs = T._METRICS[metric](q[:, :d_probe], centroids, cent_sq)
+            _, probe = jax.lax.top_k(-cs, min(n_probe, centroids.shape[0]))
 
-    # mask every unreturnable slot to -1 BEFORE the kernel: list padding is
-    # already -1, tombstoned rows come from the live validity bits (the
-    # packed member vectors are a build-time snapshot)
-    member_ids = lists
-    if valid is not None:
-        member_ids = jnp.where(
-            (lists >= 0) & valid[jnp.maximum(lists, 0)], lists, -1)
+        # mask every unreturnable slot to -1 BEFORE the kernel: list padding
+        # is already -1, tombstoned rows come from the live validity bits
+        # (the packed member vectors are a build-time snapshot)
+        member_ids = lists
+        if valid is not None:
+            with jax.named_scope("member_mask"):
+                member_ids = jnp.where(
+                    (lists >= 0) & valid[jnp.maximum(lists, 0)], lists, -1)
 
-    pack = {
-        "rows": pack_rows, "sq": pack_sq, "scale": pack_scale,
-        "codebooks": pack_codebooks, "cent_sq": pack_cent_sq,
-        "dim": pack_meta[0], "max_len": pack_meta[1],
-        "block_m": pack_meta[2], "dtype": pack_meta[3],
-    }
-    if pack_meta[3] == "pq":
-        # oversampled survivor pool: the classic PQ remedy for ADC ranking
-        # noise — the full-precision rescore ladder cuts it back
-        k0_eff = s0.k * pq_oversample
-        scores, cand = pq_ivf_scan_topk(
-            q, probe, member_ids, pack, k=k0_eff, interpret=interpret)
-    else:
-        k0_eff = s0.k
-        scores, cand = ivf_scan_topk(
-            q, probe, member_ids, pack, k=k0_eff, interpret=interpret)
+        pack = {
+            "rows": pack_rows, "sq": pack_sq, "scale": pack_scale,
+            "codebooks": pack_codebooks, "cent_sq": pack_cent_sq,
+            "dim": pack_meta[0], "max_len": pack_meta[1],
+            "block_m": pack_meta[2], "dtype": pack_meta[3],
+        }
+        with jax.named_scope("scan"):
+            if pack_meta[3] == "pq":
+                # oversampled survivor pool: the classic PQ remedy for ADC
+                # ranking noise — the full-precision rescore ladder cuts it
+                # back
+                k0_eff = s0.k * pq_oversample
+                scores, cand = pq_ivf_scan_topk(
+                    q, probe, member_ids, pack, k=k0_eff,
+                    interpret=interpret)
+            else:
+                k0_eff = s0.k
+                scores, cand = ivf_scan_topk(
+                    q, probe, member_ids, pack, k=k0_eff,
+                    interpret=interpret)
 
-    if extra_cand is not None:
-        # the un-indexed tail window competes in stage 0 exactly as the XLA
-        # path's inject_candidates placement: rescore the (few) tail rows at
-        # the stage-0 dim and fold them into the kernel's top-k
-        e = extra_cand.shape[0]
-        tail_tbl = jnp.broadcast_to(
-            extra_cand[None, :], (q.shape[0], e))
-        # keep as many tail survivors as the (possibly oversampled) pool
-        # can seat — capping at s0.k would let coded rows crowd appended
-        # rows out of pool slots they outscore
-        ts, ti = T.rescore_candidates(
-            q, db, tail_tbl, dim=s0.dim, k=min(k0_eff, e),
-            db_sq_at_dim=_sq_col(sq_prefix, index_dims, s0.dim),
-            valid=valid, metric=metric,
-        )
-        cat_s = jnp.concatenate([scores, ts], axis=1)
-        cat_i = jnp.concatenate([cand, ti], axis=1)
-        neg, pos = jax.lax.top_k(-cat_s, k0_eff)
-        scores = -neg
-        cand = jnp.take_along_axis(cat_i, pos, axis=1)
+        if extra_cand is not None:
+            # the un-indexed tail window competes in stage 0 exactly as the
+            # XLA path's inject_candidates placement: rescore the (few) tail
+            # rows at the stage-0 dim and fold them into the kernel's top-k
+            with jax.named_scope("tail"):
+                e = extra_cand.shape[0]
+                tail_tbl = jnp.broadcast_to(
+                    extra_cand[None, :], (q.shape[0], e))
+                # keep as many tail survivors as the (possibly oversampled)
+                # pool can seat — capping at s0.k would let coded rows
+                # crowd appended rows out of pool slots they outscore
+                ts, ti = T.rescore_candidates(
+                    q, db, tail_tbl, dim=s0.dim, k=min(k0_eff, e),
+                    db_sq_at_dim=_sq_col(sq_prefix, index_dims, s0.dim),
+                    valid=valid, metric=metric,
+                )
+                cat_s = jnp.concatenate([scores, ts], axis=1)
+                cat_i = jnp.concatenate([cand, ti], axis=1)
+                neg, pos = jax.lax.top_k(-cat_s, k0_eff)
+                scores = -neg
+                cand = jnp.take_along_axis(cat_i, pos, axis=1)
 
-    if stage0_only:
-        return scores, cand
     return rescore_ladder(
         q, db, cand, sched.stages[1:],
         sq_prefix=sq_prefix, index_dims=index_dims,
@@ -409,7 +422,6 @@ def ivf_progressive_search_kernel(
     block_m: int = 128,
     pq_oversample: int = 1,
     interpret: bool = False,
-    stage0_only: bool = False,
 ) -> Tuple[Array, Array]:
     """`ivf_progressive_search_sched` with the fused Pallas stage-0 kernel.
 
@@ -455,5 +467,5 @@ def ivf_progressive_search_kernel(
         valid, sq_prefix, extra_cand, cent_sq, sched,
         n_probe=n_probe, index_dims=index_dims, metric=metric,
         pack_meta=pack_meta, pq_oversample=pq_oversample,
-        interpret=interpret, stage0_only=stage0_only,
+        interpret=interpret,
     )

@@ -246,30 +246,23 @@ def _stage0_ids(codes, valid, row_limit):
     return jnp.where(keep, ids, -1)
 
 
-def _finish(q, rescore_db, sched, scores, cand, *, valid, extra_cand, metric,
-            stage0_only=False):
+def _finish(q, rescore_db, sched, scores, cand, *, valid, extra_cand,
+            metric):
     """Shared post-stage-0 path: tail injection + the rescore ladder."""
     from repro.core.progressive import rescore_ladder
+    from repro.core.quant import quant_rest_stages
 
-    cand = T.inject_candidates(cand, extra_cand)
-    if stage0_only:
-        # fenced split: the ladder (`quant_rest_stages` +
-        # `rescore_ladder_jit`) scores the injected rows exactly
-        return scores, cand
-    rest = sched.stages[1:]
-    if not rest and (extra_cand is not None or valid is not None):
-        # single-stage schedule: still need one exact pass so injected /
-        # masked candidates carry full-precision scores and ranking
-        rest = (sched.stages[0],)
+    with jax.named_scope("stage0"):
+        cand = T.inject_candidates(cand, extra_cand)
     return rescore_ladder(
-        q, rescore_db, cand, rest,
+        q, rescore_db, cand,
+        quant_rest_stages(sched, extra_cand=extra_cand, valid=valid),
         valid=valid, metric=metric, scores=scores,
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sched", "metric", "oversample",
-                              "stage0_only"))
+    jax.jit, static_argnames=("sched", "metric", "oversample"))
 def pq_progressive_search(
     q: Array, idx: Dict[str, Array], sched: ProgressiveSchedule,
     *, metric: str = "l2",
@@ -278,7 +271,6 @@ def pq_progressive_search(
     row_limit: Optional[Array] = None,
     extra_cand: Optional[Array] = None,
     oversample: int = 1,
-    stage0_only: bool = False,
 ) -> Tuple[Array, Array]:
     """Progressive search with a PQ ADC stage-0 scan (XLA reference).
 
@@ -289,7 +281,8 @@ def pq_progressive_search(
     (widening the cheap stage is nearly free; the full-precision rescore
     cuts the pool back).  The mutable-corpus extensions (``db``/``valid``/
     ``row_limit``/``extra_cand``) mean exactly what they mean for
-    `repro.core.quant.quantized_progressive_search`.
+    `repro.core.quant.quantized_progressive_search`.  Named scopes:
+    ``stage0`` (LUT, ADC scan, top-k), ``rescore``.
     """
     if metric != "l2":
         raise ValueError(
@@ -300,22 +293,22 @@ def pq_progressive_search(
     codes = idx["codes"]
     n0 = codes.shape[0]
     ds = idx["codebooks"].shape[0] * idx["codebooks"].shape[2]
-    lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
-    scores = pq_adc_scores(lut, codes)
-    ids = _stage0_ids(codes, valid, row_limit)
-    scores = jnp.where(ids[None, :] >= 0, scores, jnp.inf)
-    neg, cand = jax.lax.top_k(-scores, min(s0.k * oversample, n0))
-    # fully-masked slots must surface the -1 sentinel, not row 0
-    cand = jnp.where(jnp.isfinite(-neg), cand.astype(jnp.int32), -1)
+    with jax.named_scope("stage0"):
+        lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
+        scores = pq_adc_scores(lut, codes)
+        ids = _stage0_ids(codes, valid, row_limit)
+        scores = jnp.where(ids[None, :] >= 0, scores, jnp.inf)
+        neg, cand = jax.lax.top_k(-scores, min(s0.k * oversample, n0))
+        # fully-masked slots must surface the -1 sentinel, not row 0
+        cand = jnp.where(jnp.isfinite(-neg), cand.astype(jnp.int32), -1)
     return _finish(q, rescore_db, sched, -neg, cand,
-                   valid=valid, extra_cand=extra_cand, metric=metric,
-                   stage0_only=stage0_only)
+                   valid=valid, extra_cand=extra_cand, metric=metric)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("sched", "metric", "block_m", "oversample",
-                     "interpret", "stage0_only"))
+                     "interpret"))
 def pq_progressive_search_kernel(
     q: Array, idx: Dict[str, Array], sched: ProgressiveSchedule,
     *, metric: str = "l2",
@@ -326,7 +319,6 @@ def pq_progressive_search_kernel(
     block_m: int = 128,
     oversample: int = 1,
     interpret: bool = False,
-    stage0_only: bool = False,
 ) -> Tuple[Array, Array]:
     """`pq_progressive_search` with the fused Pallas ADC stage-0 kernel.
 
@@ -334,7 +326,8 @@ def pq_progressive_search_kernel(
     `tests/test_kernels.py` enforces), but stage 0 runs
     `repro.kernels.pq_scan.pq_scan_topk`: the per-query (M, C) LUT stays
     VMEM-resident while uint8 code slabs stream HBM→VMEM once and the
-    running top-k never leaves VMEM.
+    running top-k never leaves VMEM.  Named scopes: ``stage0`` (LUT and the
+    fused scan), ``rescore``.
     """
     from repro.kernels.pq_scan import pq_scan_topk
 
@@ -347,11 +340,11 @@ def pq_progressive_search_kernel(
     codes = idx["codes"]
     n0 = codes.shape[0]
     ds = idx["codebooks"].shape[0] * idx["codebooks"].shape[2]
-    lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
-    ids = _stage0_ids(codes, valid, row_limit)
-    scores, cand = pq_scan_topk(
-        lut, codes, ids, k=min(s0.k * oversample, n0), block_m=block_m,
-        interpret=interpret)
+    with jax.named_scope("stage0"):
+        lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
+        ids = _stage0_ids(codes, valid, row_limit)
+        scores, cand = pq_scan_topk(
+            lut, codes, ids, k=min(s0.k * oversample, n0), block_m=block_m,
+            interpret=interpret)
     return _finish(q, rescore_db, sched, scores, cand,
-                   valid=valid, extra_cand=extra_cand, metric=metric,
-                   stage0_only=stage0_only)
+                   valid=valid, extra_cand=extra_cand, metric=metric)

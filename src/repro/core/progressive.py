@@ -68,56 +68,26 @@ def rescore_ladder(
     stage-0 scan, IVF after probing, quantized after the int8 scan).
 
     ``scores`` is returned unchanged when ``stages`` is empty (degenerate
-    single-stage schedules).
+    single-stage schedules).  The ladder runs under the named scope
+    ``rescore``; stage-0 work never calls it, so a device trace counts
+    each operation under one of ``stage0`` and ``rescore``.
     """
     index = {"sq_prefix": sq_prefix} if sq_prefix is not None else None
-    for stage in stages:
-        scores, cand = T.rescore_candidates(
-            q, db, cand,
-            dim=stage.dim, k=stage.k,
-            db_sq_at_dim=_prefix_sq(index, index_dims, stage.dim),
-            valid=valid,
-            metric=metric,
-        )
+    with jax.named_scope("rescore"):
+        for stage in stages:
+            scores, cand = T.rescore_candidates(
+                q, db, cand,
+                dim=stage.dim, k=stage.k,
+                db_sq_at_dim=_prefix_sq(index, index_dims, stage.dim),
+                valid=valid,
+                metric=metric,
+            )
     return scores, cand
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("stages", "index_dims", "metric"),
-)
-def rescore_ladder_jit(
-    q: Array,
-    db: Array,
-    cand: Array,
-    stages,
-    *,
-    sq_prefix: Optional[Array] = None,
-    index_dims: Optional[tuple] = None,
-    valid: Optional[Array] = None,
-    metric: str = "l2",
-    scores: Optional[Array] = None,
-) -> Tuple[Array, Array]:
-    """Jitted ``rescore_ladder`` — the second half of a fenced search.
-
-    The fused entry points (`progressive_search` and the IVF / quantized /
-    PQ variants) jit stage-0 + ladder as one XLA program.  Observability
-    stage fences (``obs.stage_fences``) instead run stage-0 with
-    ``stage0_only=True``, ``block_until_ready`` the candidates to timestamp
-    the stage-0/rescore boundary, then finish through this program.
-    ``stages`` must be a (hashable) tuple of `Stage`.
-    """
-    return rescore_ladder(
-        q, db, cand, stages,
-        sq_prefix=sq_prefix, index_dims=index_dims,
-        valid=valid, metric=metric, scores=scores,
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("sched", "index_dims", "block_n", "metric",
-                     "stage0_only"),
+    static_argnames=("sched", "index_dims", "block_n", "metric"),
 )
 def progressive_search(
     q: Array,
@@ -129,7 +99,6 @@ def progressive_search(
     valid: Optional[Array] = None,
     block_n: int = 65536,
     metric: str = "l2",
-    stage0_only: bool = False,
 ) -> Tuple[Array, Array]:
     """Per-query progressive search (static shapes; jit/pjit-native).
 
@@ -144,9 +113,10 @@ def progressive_search(
                   serving: deleted / unpopulated rows are unreturnable).
       block_n:    document tile for the stage-0 full scan.
       metric:     'l2' or 'cosine'.
-      stage0_only: static; return the stage-0 (scores, candidates) without
-                  the rescore ladder — the fenced-observability split point
-                  (finish via ``rescore_ladder_jit`` on ``stages[1:]``).
+
+    The program carries two named scopes, so a device trace splits its
+    time: ``stage0`` (column slice, padding, blocked scan, top-k) and
+    ``rescore`` (the ladder).
 
     Returns:
       (scores, indices): ((Q, final_k) float32, (Q, final_k) int32).
@@ -154,15 +124,14 @@ def progressive_search(
     index = {"sq_prefix": sq_prefix} if sq_prefix is not None else None
 
     s0 = sched.stages[0]
-    scores, cand = T.truncated_search(
-        q, db,
-        dim=s0.dim, k=s0.k,
-        db_sq_at_dim=_prefix_sq(index, index_dims, s0.dim),
-        valid=valid,
-        block_n=block_n, metric=metric,
-    )
-    if stage0_only:
-        return scores, cand
+    with jax.named_scope("stage0"):
+        scores, cand = T.truncated_search(
+            q, db,
+            dim=s0.dim, k=s0.k,
+            db_sq_at_dim=_prefix_sq(index, index_dims, s0.dim),
+            valid=valid,
+            block_n=block_n, metric=metric,
+        )
     return rescore_ladder(
         q, db, cand, sched.stages[1:],
         sq_prefix=sq_prefix, index_dims=index_dims,

@@ -169,22 +169,17 @@ def _scaled_space_scores(q: Array, idx: Dict[str, Array]) -> Array:
 
 
 def quant_rest_stages(sched, *, extra_cand=None, valid=None):
-    """Post-stage-0 ladder stages for the quantized / PQ families.
-
-    Mirrors the fused paths' ``rest`` logic so a fenced search
-    (``stage0_only=True`` + `rescore_ladder_jit`) refines through exactly
-    the stages the fused program would: ``stages[1:]``, except a
-    single-stage schedule with injected or masked candidates still needs
-    one exact pass so those candidates carry full-precision scores.
-    """
+    """Post-stage-0 ladder stages for the quantized / PQ families:
+    ``stages[1:]``, except that a single-stage schedule with injected or
+    masked candidates still needs one exact pass so those candidates carry
+    full-precision scores."""
     rest = sched.stages[1:]
     if not rest and (extra_cand is not None or valid is not None):
         rest = (sched.stages[0],)
     return rest
 
 
-@functools.partial(jax.jit, static_argnames=("sched", "metric",
-                                             "stage0_only"))
+@functools.partial(jax.jit, static_argnames=("sched", "metric"))
 def quantized_progressive_search(
     q: Array, idx: Dict[str, Array], sched: ProgressiveSchedule,
     *, metric: str = "l2",
@@ -192,7 +187,6 @@ def quantized_progressive_search(
     valid: Optional[Array] = None,
     row_limit: Optional[Array] = None,
     extra_cand: Optional[Array] = None,
-    stage0_only: bool = False,
 ) -> Tuple[Array, Array]:
     """Progressive search with an int8 stage-0 block.
 
@@ -212,34 +206,29 @@ def quantized_progressive_search(
                   keep them reachable.
       extra_cand: (E,) int32 ids injected after stage 0 (-1 padded), rescored
                   at full precision; must be disjoint from stage-0 rows.
+
+    Named scopes: ``stage0`` (the int8 scan and top-k), ``rescore``.
     """
     from repro.core.progressive import rescore_ladder
 
     s0 = sched.stages[0]
     rescore_db = idx["db"] if db is None else db
-    scores = _scaled_space_scores(q, idx)
-    n0 = scores.shape[1]
-    keep = jnp.ones((n0,), bool)
-    if valid is not None:
-        keep = keep & valid[:n0]
-    if row_limit is not None:
-        keep = keep & (jnp.arange(n0) < row_limit)
-    scores = jnp.where(keep[None, :], scores, jnp.inf)
-    neg, cand = jax.lax.top_k(-scores, min(s0.k, n0))
-    # fully-masked slots must surface the -1 sentinel, not row 0
-    cand = jnp.where(jnp.isfinite(-neg), cand.astype(jnp.int32), -1)
-    scores = -neg
-    cand = T.inject_candidates(cand, extra_cand)
-    if stage0_only:
-        # fenced split: injected tail rows ride along unscored — the ladder
-        # (`quant_rest_stages` + `rescore_ladder_jit`) scores them exactly
-        return scores, cand
-    rest = sched.stages[1:]
-    if not rest and (extra_cand is not None or valid is not None):
-        # single-stage schedule: still need one exact pass so injected /
-        # masked candidates carry full-precision scores and ranking
-        rest = (s0,)
+    with jax.named_scope("stage0"):
+        scores = _scaled_space_scores(q, idx)
+        n0 = scores.shape[1]
+        keep = jnp.ones((n0,), bool)
+        if valid is not None:
+            keep = keep & valid[:n0]
+        if row_limit is not None:
+            keep = keep & (jnp.arange(n0) < row_limit)
+        scores = jnp.where(keep[None, :], scores, jnp.inf)
+        neg, cand = jax.lax.top_k(-scores, min(s0.k, n0))
+        # fully-masked slots must surface the -1 sentinel, not row 0
+        cand = jnp.where(jnp.isfinite(-neg), cand.astype(jnp.int32), -1)
+        scores = -neg
+        cand = T.inject_candidates(cand, extra_cand)
     return rescore_ladder(
-        q, rescore_db, cand, rest,
+        q, rescore_db, cand,
+        quant_rest_stages(sched, extra_cand=extra_cand, valid=valid),
         valid=valid, metric=metric, scores=scores,
     )

@@ -196,24 +196,18 @@ class ObsConfig:
     """Observability section of `EngineConfig` (see `repro.obs`).
 
     * ``enabled`` — master switch.  ``False`` degrades every metric
-      instrument to a shared no-op and skips trace contexts entirely,
-      restoring the uninstrumented fast path (the overhead benchmark's
-      baseline).
+      instrument and every profiler span (``repro.obs.span``) to a shared
+      no-op and skips trace contexts entirely, restoring the
+      uninstrumented fast path (the overhead benchmark's baseline).
     * ``slow_query_ms`` — latency threshold for the structured JSON
       slow-query log (None disables the log).
     * ``trace_ring`` — capacity of the in-memory ring of recent request
       traces (0 disables it).
-    * ``stage_fences`` — opt-in ``block_until_ready`` fence between the
-      stage-0 scan and the rescore ladder on the batched (driver) path, so
-      traces carry a real stage-0/rescore split.  Off by default: the
-      fence costs one extra host sync per batch, and the default path
-      stays fused exactly as before.
     """
 
     enabled: bool = True
     slow_query_ms: Optional[float] = None
     trace_ring: int = 256
-    stage_fences: bool = False
 
     def __post_init__(self):
         if self.slow_query_ms is not None and self.slow_query_ms < 0:
@@ -590,9 +584,6 @@ class EngineConfig:
                              "slower than this (0 = disabled)")
         ap.add_argument("--trace-ring", type=int, default=256,
                         help="recent-request trace ring capacity")
-        ap.add_argument("--stage-fences", action="store_true",
-                        help="fence stage-0 vs rescore on the batched path "
-                             "so traces carry the split (extra host sync)")
         ap.add_argument("--adaptive", action="store_true",
                         help="enable the load-adaptive search policy "
                              "(degrade recall instead of availability "
@@ -688,7 +679,6 @@ class EngineConfig:
                 enabled=not args.no_obs,
                 slow_query_ms=args.slow_query_ms or None,
                 trace_ring=args.trace_ring,
-                stage_fences=args.stage_fences,
             ),
             adaptive=AdaptiveConfig(
                 enabled=args.adaptive,

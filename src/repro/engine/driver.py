@@ -37,6 +37,9 @@ serving system has many client threads and nobody whose job is to call
   iterations (PR 2's safe-point contract), never mid-batch.  Corpus
   mutations from client threads serialize against dispatches on
   ``engine.lock``.
+* **Profiler spans** — the loop's waits are ``repro.driver.idle`` (empty
+  queue) and ``repro.driver.hold`` (requests pending under the batching
+  deadline); each dispatch is ``repro.driver.execute``.
 """
 
 from __future__ import annotations
@@ -51,6 +54,7 @@ from repro.engine.adaptive import AdaptivePolicy
 from repro.engine.batching import DeadlineBatcher, PendingRequest
 from repro.engine.engine import RequestStats, RetrievalEngine, RetrievalResult
 from repro.engine.qcache import QueryCache
+from repro.obs import span
 
 
 class DriverStopped(RuntimeError):
@@ -263,6 +267,7 @@ class EngineDriver:
         self._wait_samples: Deque[float] = deque(maxlen=128)
         engine.metrics.register_collector(self._collect_metrics)
         self._clock = clock
+        self._spans = bool(engine.config.obs.enabled)
         self._max_queue = int(max_queue)
         self._name = name
         self._pending: Deque[_Pending] = deque()
@@ -628,7 +633,11 @@ class EngineDriver:
         kw = {} if overrides is None or overrides.level == 0 \
             else {"overrides": overrides}
         try:
-            results = self.engine.execute_batch([p.req for p in chunk], **kw)
+            with span("driver.execute", self._spans,
+                      bucket=self.engine.policy.bucket_for(len(chunk)),
+                      fill=len(chunk)):
+                results = self.engine.execute_batch(
+                    [p.req for p in chunk], **kw)
         except Exception as e:
             # fail this batch's clients — or, with bisection enabled,
             # isolate the offender so its co-batched neighbours still get
@@ -736,16 +745,19 @@ class EngineDriver:
                             if self._supervised:
                                 w = min(w, self.engine.config.fault
                                         .heartbeat_timeout_s / 2)
-                            self._cv.wait(w)
+                            with span("driver.hold", self._spans):
+                                self._cv.wait(w)
                         elif (self.adaptive is not None
                                 and self.adaptive.level > 0):
                             # idle while degraded: wake periodically so the
                             # hysteretic recovery can tick even with no
                             # arrivals to prod the loop
-                            self._cv.wait(
-                                max(0.05, self.adaptive.cfg.hysteresis_s / 4))
+                            with span("driver.idle", self._spans):
+                                self._cv.wait(max(
+                                    0.05, self.adaptive.cfg.hysteresis_s / 4))
                         else:                     # idle: block for arrivals
-                            self._cv.wait()
+                            with span("driver.idle", self._spans):
+                                self._cv.wait()
                     self._cv.notify_all()         # queue space freed
                 # dispatch outside the cv so producers keep submitting while
                 # the device computes (engine.lock still serializes engine

@@ -26,8 +26,9 @@ surveys in PAPERS.md):
   buffers are rebuilt without tombstones (live doc ids are REMAPPED —
   ``on_remap`` callbacks let id-holding callers follow).
 * **Observability** — per-request latency (queue + compute split), per-batch
-  padding waste, rebuild/compaction counts, and a stage-by-stage timing
-  profile (``profile_stages``) for roofline work.
+  padding waste, rebuild/compaction counts, and profiler spans at each host
+  seam of a dispatch (``repro.engine.*``; the device programs carry
+  ``stage0`` / ``rescore`` named scopes on the same trace).
 
 The engine is synchronous and single-host by design: ``step()`` is the unit a
 driver loop calls, and ``execute_batch()`` is the direct entry point the
@@ -54,13 +55,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import (
-    ProgressiveSchedule,
-    make_schedule,
-    rescore_candidates,
-    stage_dims,
-    truncated_search,
-)
+from repro.core import ProgressiveSchedule, make_schedule, stage_dims
 from repro.engine.adaptive import SearchOverrides
 from repro.engine.batching import BucketPolicy, PendingRequest, RequestQueue, pad_batch
 from repro.engine.config import EngineConfig, legacy_config
@@ -70,11 +65,13 @@ from repro.engine.store import DocStore
 from repro.engine.wal import MutationWAL, WALError
 from repro.index_backends import IndexBackend, IndexState, make_backend
 from repro.obs import (
+    COMPILES,
     NULL_INSTRUMENT,
     MetricsRegistry,
     SlowQueryLog,
     TraceContext,
     TraceRing,
+    span,
 )
 
 Array = jax.Array
@@ -111,12 +108,10 @@ class RequestStats:
     compute_ms: float          # batch dispatch -> device done (shared by batch)
     bucket: int                # static batch size the request rode in
     batch_fill: int            # real requests in that batch (<= bucket)
-    compiled: bool             # this dispatch triggered an XLA compile
-    # stage-split timings, present only under ``obs.stage_fences`` (the
-    # fenced dispatch syncs once at the stage-0 boundary; the default fast
-    # path stays fused and reports them as None)
-    stage0_ms: Optional[float] = None     # dispatch -> stage-0 scan done
-    rescore_ms: Optional[float] = None    # stage-0 done -> rescore done
+    # first dispatch of this (bucket, capacity, index, overrides) shape on
+    # this engine — the dispatch that compiles, unless the program is
+    # already in JAX's caches (`repro.obs.COMPILES` counts real compiles)
+    compiled: bool
     # full trace-mark offsets from submit (``TraceContext.spans_ms``);
     # None when ``obs.enabled=False``
     spans: Optional[Dict[str, float]] = None
@@ -154,7 +149,8 @@ _ENGINE_COUNTERS = {
                     "Requests completed with a result"),
     "n_batches": ("repro_engine_batches_total", "Batches dispatched"),
     "n_compiles": ("repro_engine_compiles_total",
-                   "Dispatches that triggered an XLA compile"),
+                   "Dispatches of a shape this engine had not dispatched "
+                   "before (repro_jax_compiles_total counts XLA compiles)"),
     "n_padded_slots": ("repro_engine_padded_slots_total",
                        "Padding rows dispatched (bucket minus fill)"),
     "n_docs_added": ("repro_engine_docs_added_total", "Documents appended"),
@@ -195,8 +191,6 @@ class EngineStats:
         self.h_latency = NULL_INSTRUMENT
         self.h_queue = NULL_INSTRUMENT
         self.h_compute = NULL_INSTRUMENT
-        self.h_stage0 = NULL_INSTRUMENT
-        self.h_rescore = NULL_INSTRUMENT
         self.h_rebuild = NULL_INSTRUMENT
         self.h_compact = NULL_INSTRUMENT
         self.c_batch_bucket = NULL_INSTRUMENT
@@ -220,12 +214,6 @@ class EngineStats:
         self.h_compute = registry.histogram(
             "repro_engine_batch_compute_ms",
             "Dispatch-to-device-done per batch")
-        self.h_stage0 = registry.histogram(
-            "repro_engine_stage0_ms",
-            "Stage-0 scan span (obs.stage_fences only)")
-        self.h_rescore = registry.histogram(
-            "repro_engine_rescore_ms",
-            "Rescore-ladder span (obs.stage_fences only)")
         self.h_rebuild = registry.histogram(
             "repro_engine_rebuild_ms", "Index build duration")
         self.h_compact = registry.histogram(
@@ -267,10 +255,6 @@ class EngineStats:
         # the scrape invariant latency_ms_count == requests_completed_total
         self.h_latency.observe_many([st.latency_ms for st in sts])
         self.h_queue.observe_many([st.queue_ms for st in sts])
-        if sts and sts[0].stage0_ms is not None:
-            # batch-uniform: the fence timestamps come from one dispatch
-            self.h_stage0.observe_many([st.stage0_ms for st in sts])
-            self.h_rescore.observe_many([st.rescore_ms for st in sts])
         for st in sts:
             if st.compiled:
                 # compile-inflated latencies would skew steady-state
@@ -498,7 +482,6 @@ class RetrievalEngine:
         self.trace_ring = TraceRing(obs.trace_ring)
         self.slow_log = SlowQueryLog(obs.slow_query_ms)
         self._obs_enabled = bool(obs.enabled)
-        self._stage_fences = bool(obs.stage_fences and obs.enabled)
         self._c_slow = self.metrics.counter(
             "repro_slow_queries_total",
             "Requests over obs.slow_query_ms (also emitted to the "
@@ -519,6 +502,17 @@ class RetrievalEngine:
         self._c_mask_misses = self.metrics.counter(
             "repro_store_mask_cache_misses_total",
             "Compiled tenant/filter mask cache misses (mask recompiles)")
+        # process-wide XLA compile log (`repro.obs.compiles`)
+        self._c_jax_compiles = self.metrics.counter(
+            "repro_jax_compiles_total",
+            "XLA backend compiles in this process, persistent-cache loads "
+            "included")
+        self._c_jax_compile_s = self.metrics.counter(
+            "repro_jax_compile_seconds_total",
+            "Seconds spent in those compiles and loads")
+        self._c_jax_cache_hits = self.metrics.counter(
+            "repro_jax_compile_cache_hits_total",
+            "Programs loaded from JAX's persistent compilation cache")
         self.metrics.register_collector(self._collect_metrics)
 
         self.backend: IndexBackend = (
@@ -1151,26 +1145,28 @@ class RetrievalEngine:
         ``overrides`` (adaptive policy) degrades the whole batch's search
         knobs; ``None`` is the static full-quality path.
         """
-        self._maybe_rebuild_locked()              # safe point between batches
+        on = self._obs_enabled
+        with span("engine.rebuild", on):
+            self._maybe_rebuild_locked()          # safe point between batches
         # compile AFTER the rebuild safe point: appends/compaction already
         # landed, so the mask matches the buffers this dispatch will scan
-        mask = self.store.mask_for_key(reqs[0].mask_key)
+        with span("engine.mask", on):
+            mask = self.store.mask_for_key(reqs[0].mask_key)
         bucket = self.policy.bucket_for(len(reqs))
         t_dispatch = time.perf_counter()
         qb = pad_batch(np.stack([r.query for r in reqs]), bucket)
-        if self._stage_fences:
-            scores, ids, compiled, t_stage0 = self._dispatch_fenced(
-                qb, mask=mask, overrides=overrides)
-        else:
-            scores, ids, compiled = self._dispatch(
-                qb, mask=mask, overrides=overrides)
-            t_stage0 = None
+        scores, ids, compiled = self._dispatch(
+            qb, mask=mask, overrides=overrides)
         t_done = time.perf_counter()
+        with span("engine.results", on):
+            return self._results_of(reqs, overrides, bucket, scores, ids,
+                                    compiled, t_dispatch, t_done)
+
+    def _results_of(self, reqs, overrides, bucket, scores, ids, compiled,
+                    t_dispatch, t_done) -> List[RetrievalResult]:
+        """Per-request results, stats and trace records of one dispatched
+        batch (caller holds ``self.lock``)."""
         compute_ms = (t_done - t_dispatch) * 1e3
-        stage0_ms = (None if t_stage0 is None
-                     else (t_stage0 - t_dispatch) * 1e3)
-        rescore_ms = (None if t_stage0 is None
-                      else (t_done - t_stage0) * 1e3)
         self.stats.record_batch(bucket, len(reqs), compute_ms, compiled)
         out = []
         sts = []
@@ -1192,9 +1188,6 @@ class RetrievalEngine:
                 if t is not None:
                     spans["batch"] = (t - t0_req) * 1e3
                 spans["dispatch"] = (t_dispatch - t0_req) * 1e3
-                if t_stage0 is not None:
-                    spans["stage0"] = (t_stage0 - t0_req) * 1e3
-                    spans["rescore"] = (t_done - t0_req) * 1e3
                 spans["deliver"] = (t_done - t0_req) * 1e3
             st = RequestStats(
                 latency_ms=(t_done - r.t_submit) * 1e3,
@@ -1203,8 +1196,6 @@ class RetrievalEngine:
                 bucket=bucket,
                 batch_fill=len(reqs),
                 compiled=compiled,
-                stage0_ms=stage0_ms,
-                rescore_ms=rescore_ms,
                 spans=spans,
             )
             sts.append(st)
@@ -1327,12 +1318,8 @@ class RetrievalEngine:
             # static argnames), so pressure transitions never compile
             for ov in (None, *self._level_overrides.values()):
                 for b in self.policy.sizes:
-                    qb = np.repeat(probe, b, axis=0)
-                    # warm whichever dispatch path requests actually take
-                    if self._stage_fences:
-                        self._dispatch_fenced(qb, overrides=ov)
-                    else:
-                        self._dispatch(qb, overrides=ov)
+                    self._dispatch(np.repeat(probe, b, axis=0),
+                                   overrides=ov)
 
     # -- synchronous batch API (pipeline / benchmarks) ------------------------
     def search(self, queries, *, k: Optional[int] = None,
@@ -1417,59 +1404,34 @@ class RetrievalEngine:
                      overrides)
         compiled = shape_key not in self._seen_shapes
         self._seen_shapes.add(shape_key)
-        valid = (store.valid if mask is None
-                 else jnp.logical_and(store.valid, mask))
+        valid = store.valid
+        if mask is not None:
+            with span("engine.mask", self._obs_enabled):
+                valid = jnp.logical_and(valid, mask)
         # overrides passed only when set: pre-existing custom backends that
         # never heard of the kwarg keep working on the static path
         kw = {} if overrides is None else {"overrides": overrides}
-        s, i = self.backend.search(
-            jnp.asarray(q_pad), state, store.db, valid,
-            sq_prefix=store.sq_prefix,
-            n_total=store.size,
-            k=self.out_k,
-            **kw,
-        )
+        with span("engine.enqueue", self._obs_enabled,
+                  bucket=q_pad.shape[0]):
+            s, i = self.backend.search(
+                jnp.asarray(q_pad), state, store.db, valid,
+                sq_prefix=store.sq_prefix,
+                n_total=store.size,
+                k=self.out_k,
+                **kw,
+            )
         return s, i, compiled
 
     def _dispatch(self, q_pad: np.ndarray, mask=None, overrides=None):
+        """One padded bucket, start to finish: enqueue, wait for the device,
+        copy the results to the host.  Returns (scores, ids, compiled)."""
         s, i, compiled = self._dispatch_async(q_pad, mask=mask,
                                               overrides=overrides)
-        jax.block_until_ready((s, i))
-        return np.asarray(s), np.asarray(i), compiled
-
-    def _dispatch_fenced(self, q_pad: np.ndarray, mask=None, overrides=None):
-        """Dispatch with a ``block_until_ready`` fence at the stage-0
-        boundary (``obs.stage_fences``), so the stage-0 / rescore split is
-        measurable.  Two device round trips instead of one fused program —
-        an opt-in diagnostic path with its own compile-cache entries (the
-        ``"fenced"`` tag keeps its shape keys apart from the fused path's).
-        Returns (scores, ids, compiled, t_stage0)."""
-        store = self.store
-        state = self._ensure_index()
-        shape_key = ("fenced", q_pad.shape[0], store.capacity,
-                     state.shape_key, overrides)
-        compiled = shape_key not in self._seen_shapes
-        self._seen_shapes.add(shape_key)
-        valid = (store.valid if mask is None
-                 else jnp.logical_and(store.valid, mask))
-        marks: Dict[str, float] = {}
-
-        def fence(arrays) -> None:
-            jax.block_until_ready(arrays)
-            marks["stage0"] = time.perf_counter()
-
-        kw = {} if overrides is None else {"overrides": overrides}
-        s, i = self.backend.search_fenced(
-            jnp.asarray(q_pad), state, store.db, valid,
-            sq_prefix=store.sq_prefix,
-            n_total=store.size,
-            k=self.out_k,
-            fence=fence,
-            **kw,
-        )
-        jax.block_until_ready((s, i))
-        return (np.asarray(s), np.asarray(i), compiled,
-                marks.get("stage0"))
+        on = self._obs_enabled
+        with span("engine.sync", on):
+            jax.block_until_ready((s, i))
+        with span("engine.fetch", on):
+            return np.asarray(s), np.asarray(i), compiled
 
     # -- observability --------------------------------------------------------
     def _collect_metrics(self) -> None:
@@ -1489,6 +1451,10 @@ class RetrievalEngine:
             # lifetime totals instead of double-counting increments
             self._c_mask_hits.set_total(store.mask_cache_hits)
             self._c_mask_misses.set_total(store.mask_cache_misses)
+            n, seconds, hits = COMPILES.totals()
+            self._c_jax_compiles.set_total(n)
+            self._c_jax_compile_s.set_total(seconds)
+            self._c_jax_cache_hits.set_total(hits)
             st = store.stats()
             for key, val in (
                 ("size", st.size), ("n_active", st.n_active),
@@ -1507,58 +1473,6 @@ class RetrievalEngine:
                 w = self.wal.summary()
                 for key in ("last_seq", "lag_records", "n_segments"):
                     self._g_wal.set(float(w[key]), key=key)
-
-    def profile_stages(self, queries, *, runs: int = 3) -> List[Dict]:
-        """Per-stage wall time for a representative batch (post-warmup).
-
-        Runs the schedule stage by stage (stage-0 full scan, then each
-        rescore) so the cost split across dims is visible — the fused
-        ``progressive_search`` program hides it.  Always profiles the flat
-        schedule path regardless of the configured backend: it answers
-        "where does the schedule spend", not "what does this backend cost"
-        (the backend split lives in ``benchmarks/backend_comparison.py``).
-        """
-        q = jnp.asarray(np.atleast_2d(np.asarray(queries, np.float32)))
-        store = self.store
-        block_n = min(self.block_n, store.capacity)
-        dims_t = self.dims
-        out = []
-        cand = None
-        for si, stage in enumerate(self.sched.stages):
-            col = dims_t.index(stage.dim)
-
-            if si == 0:
-                def fn(c=None, _s=stage):
-                    return truncated_search(
-                        q, store.db, dim=_s.dim, k=_s.k,
-                        db_sq_at_dim=store.sq_prefix[:, col],
-                        valid=store.valid, block_n=block_n,
-                        metric=self.metric,
-                    )
-            else:
-                def fn(c=cand, _s=stage):
-                    return rescore_candidates(
-                        q, store.db, c, dim=_s.dim, k=_s.k,
-                        db_sq_at_dim=store.sq_prefix[:, col],
-                        valid=store.valid, metric=self.metric,
-                    )
-            res = fn()
-            jax.block_until_ready(res)          # warmup/compile
-            ts = []
-            for _ in range(runs):
-                t0 = time.perf_counter()
-                res = fn()
-                jax.block_until_ready(res)
-                ts.append(time.perf_counter() - t0)
-            cand = res[1]
-            out.append({
-                "stage": si,
-                "dim": stage.dim,
-                "k": stage.k,
-                "pool": stage.pool,
-                "ms": float(np.median(ts) * 1e3),
-            })
-        return out
 
     def describe(self) -> str:
         return (

@@ -190,36 +190,6 @@ class IndexBackend(abc.ABC):
         not change with ``overrides``.
         """
 
-    def search_fenced(
-        self,
-        q: Array,
-        state: IndexState,
-        db: Array,
-        valid: Array,
-        *,
-        sq_prefix: Optional[Array] = None,
-        n_total: int,
-        k: int,
-        fence,
-        overrides=None,
-    ) -> Tuple[Array, Array]:
-        """`search` with a host fence at the stage-0/rescore boundary.
-
-        ``fence(arrays)`` is an engine-supplied callback: implementations
-        call it exactly once with the stage-0 outputs; the engine
-        ``block_until_ready``s them there and timestamps the boundary
-        (`repro.obs` trace marks).  This path trades one extra host sync
-        per batch for a real stage-0/rescore latency split — it is only
-        selected under ``obs.stage_fences``; the default serving path keeps
-        the fully fused programs.
-
-        Default: fall back to the fused `search` without calling ``fence``
-        (custom backends degrade to traces without the split).
-        """
-        kw = {} if overrides is None else {"overrides": overrides}
-        return self.search(q, state, db, valid, sq_prefix=sq_prefix,
-                           n_total=n_total, k=k, **kw)
-
     def gauges(self, state: IndexState, stats: StoreStats) -> Dict[str, float]:
         """Point-in-time observability gauges for this state (staleness,
         tail occupancy, code coverage, ...), published by the engine's

@@ -14,7 +14,6 @@ from typing import Optional, Tuple
 import jax
 
 from repro.core import progressive_search
-from repro.core.progressive import rescore_ladder_jit
 from repro.index_backends.base import (
     IndexBackend,
     IndexState,
@@ -70,36 +69,4 @@ class FlatProgressiveBackend(IndexBackend):
         )
         # scores ascend; the leading k columns are the top results (only a
         # single-stage schedule is wider than the engine's out_k)
-        return scores[:, :k], ids[:, :k]
-
-    def search_fenced(
-        self,
-        q: Array,
-        state: IndexState,
-        db: Array,
-        valid: Array,
-        *,
-        sq_prefix: Optional[Array] = None,
-        n_total: int,
-        k: int,
-        fence,
-        overrides=None,
-    ) -> Tuple[Array, Array]:
-        sched = self.sched if overrides is None or overrides.sched is None \
-            else overrides.sched
-        scores, cand = progressive_search(
-            q, db, sched,
-            sq_prefix=sq_prefix,
-            index_dims=self.dims,
-            valid=valid,
-            block_n=min(self.block_n, db.shape[0]),
-            metric=self.metric,
-            stage0_only=True,
-        )
-        fence((scores, cand))
-        scores, ids = rescore_ladder_jit(
-            q, db, cand, sched.stages[1:],
-            sq_prefix=sq_prefix, index_dims=self.dims,
-            valid=valid, metric=self.metric, scores=scores,
-        )
         return scores[:, :k], ids[:, :k]

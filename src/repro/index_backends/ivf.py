@@ -52,7 +52,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import progressive_search
-from repro.core.progressive import rescore_ladder_jit
 from repro.core.ivf import (
     balanced_assign,
     ivf_progressive_search_kernel,
@@ -546,64 +545,6 @@ class IVFProgressiveBackend(ChurnRebuildBackend):
         if pq_os > 1:
             pq_os = max(1, int(round(pq_os * overrides.oversample_frac)))
         return sched, n_probe, pq_os
-
-    def search_fenced(
-        self,
-        q: Array,
-        state: IndexState,
-        db: Array,
-        valid: Array,
-        *,
-        sq_prefix: Optional[Array] = None,
-        n_total: int,
-        k: int,
-        fence,
-        overrides=None,
-    ) -> Tuple[Array, Array]:
-        sched, n_probe, pq_os = self._apply_overrides(state, overrides)
-        if state.data.get("flat"):
-            scores, cand = progressive_search(
-                q, db, sched,
-                sq_prefix=sq_prefix, index_dims=self.dims,
-                valid=valid, block_n=min(self.block_n, db.shape[0]),
-                metric=self.metric, stage0_only=True,
-            )
-            fence((scores, cand))
-            ladder_stages = sched.stages[1:]
-        else:
-            tail = jnp.asarray(self._tail_ids(state, n_total))
-            if state.data["pack"] is not None:
-                scores, cand = ivf_progressive_search_kernel(
-                    q, db, state.data["centroids"], state.data["lists"],
-                    self.sched, n_probe=n_probe,
-                    valid=valid, sq_prefix=sq_prefix, index_dims=self.dims,
-                    extra_cand=tail, metric=self.metric,
-                    cent_sq=state.data["cent_sq"], pack=state.data["pack"],
-                    pq_oversample=pq_os,
-                    interpret=self._interpret(),
-                    stage0_only=True,
-                )
-                fence((scores, cand))
-                ladder_stages = self.sched.stages[1:]
-            else:
-                # the sched path has no stage-0 scores: probing only gathers
-                # candidates, and ALL schedule stages rescore them
-                scores, cand = ivf_progressive_search_sched(
-                    q, db, state.data["centroids"], state.data["lists"],
-                    sched, n_probe=n_probe,
-                    valid=valid, sq_prefix=sq_prefix, index_dims=self.dims,
-                    extra_cand=tail, metric=self.metric,
-                    cent_sq=state.data["cent_sq"],
-                    stage0_only=True,
-                )
-                fence(cand)
-                ladder_stages = sched.stages
-        scores, ids = rescore_ladder_jit(
-            q, db, cand, ladder_stages,
-            sq_prefix=sq_prefix, index_dims=self.dims,
-            valid=valid, metric=self.metric, scores=scores,
-        )
-        return scores[:, :k], ids[:, :k]
 
     def gauges(self, state: IndexState, stats: StoreStats):
         out = super().gauges(state, stats)

@@ -34,12 +34,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.progressive import rescore_ladder_jit
 from repro.core.quant import (
     build_quantized_index,
     int8_encode,
     pad_pow2,
-    quant_rest_stages,
     quantized_progressive_search,
     scatter_rows,
     scatter_rows2,
@@ -298,53 +296,6 @@ class QuantizedProgressiveBackend(ChurnRebuildBackend):
             return self.pq_oversample
         return max(1, int(round(
             self.pq_oversample * overrides.oversample_frac)))
-
-    def search_fenced(
-        self,
-        q: Array,
-        state: IndexState,
-        db: Array,
-        valid: Array,
-        *,
-        sq_prefix: Optional[Array] = None,
-        n_total: int,
-        k: int,
-        fence,
-        overrides=None,
-    ) -> Tuple[Array, Array]:
-        idx = state.data["idx"]
-        tail = jnp.asarray(self._tail_ids(state, n_total))
-        pq_os = self._oversample(overrides)
-        kw = dict(
-            metric=self.metric, db=db, valid=valid,
-            row_limit=jnp.asarray(state.data["coded_upto"]),
-            extra_cand=tail, stage0_only=True,
-        )
-        if self.codec == "pq":
-            from repro.core.pq import (
-                pq_progressive_search,
-                pq_progressive_search_kernel,
-            )
-            if self._kernel_enabled():
-                scores, cand = pq_progressive_search_kernel(
-                    q, idx, self.sched, block_m=self.kernel_block_m,
-                    oversample=pq_os,
-                    interpret=self._interpret(), **kw)
-            else:
-                scores, cand = pq_progressive_search(
-                    q, idx, self.sched, oversample=pq_os, **kw)
-        else:
-            scores, cand = quantized_progressive_search(
-                q, idx, self.sched, **kw)
-        fence((scores, cand))
-        # the stage-0 outputs already carry the injected tail; finish with
-        # the same ladder stages the fused paths' rest logic would pick
-        rest = quant_rest_stages(self.sched, extra_cand=tail, valid=valid)
-        scores, ids = rescore_ladder_jit(
-            q, db, cand, rest,
-            valid=valid, metric=self.metric, scores=scores,
-        )
-        return scores[:, :k], ids[:, :k]
 
     def gauges(self, state: IndexState, stats: StoreStats):
         out = super().gauges(state, stats)

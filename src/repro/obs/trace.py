@@ -1,4 +1,12 @@
-"""Per-request trace spans: monotonic pipeline timestamps + slow-query log.
+"""Profiler spans, per-request trace marks and the slow-query log.
+
+``span(name, **args)`` opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>``: a host span on the profiler's own timeline, the clock the
+device's ``XLA Ops`` line is on, so a captured profile shows which seam of
+the program the host was in while the device ran or idled.  With no
+profile being captured a span costs one ``TraceMe`` check; with
+``ObsConfig.enabled=False`` the seams get ``NULL_SPAN``, a shared no-op.
+A span never crosses an ``await`` or a thread.
 
 A ``TraceContext`` rides on each ``PendingRequest`` through the serving
 spine and collects ``time.perf_counter()`` marks at the pipeline's seams:
@@ -7,14 +15,13 @@ spine and collects ``time.perf_counter()`` marks at the pipeline's seams:
     admit     accepted into a queue (driver pending list / engine queue)
     batch     chosen into a batch (driver ``_take_locked`` / queue pop)
     dispatch  batch handed to the backend (post rebuild + mask compile)
-    stage0    stage-0 scan fenced complete (only with ``obs.stage_fences``)
-    rescore   rescore ladder complete (only with ``obs.stage_fences``)
     deliver   result materialised on host
 
 ``spans_ms()`` converts marks to millisecond offsets from ``submit`` —
 monotone non-decreasing in pipeline order, so ``dispatch`` *is* the queue
 time and ``deliver`` is the end-to-end latency.  Marks that a given path
-does not cross (e.g. ``stage0`` on the fused fast path) are simply absent.
+does not cross (e.g. ``admit`` for requests a caller hands straight to
+``execute_batch``) are simply absent.
 
 ``TraceRing`` keeps a bounded in-memory window of recent completed traces
 for ``/v1/traces``-style debugging; ``SlowQueryLog`` emits one structured
@@ -24,15 +31,28 @@ JSON line per request whose latency exceeds the configured threshold.
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import logging
 import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # pipeline order — used for ordering output and monotonicity checks
-MARK_ORDER = ("submit", "admit", "batch", "dispatch",
-              "stage0", "rescore", "deliver")
+MARK_ORDER = ("submit", "admit", "batch", "dispatch", "deliver")
+
+SPAN_PREFIX = "repro."
+NULL_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, enabled: bool = True, **args):
+    """Context manager for the host span ``repro.<name>`` with ``args`` as
+    its profiler stats; ``NULL_SPAN`` when ``enabled`` is false."""
+    if not enabled:
+        return NULL_SPAN
+    return TraceAnnotation(SPAN_PREFIX + name, **args)
 
 slow_query_logger = logging.getLogger("repro.obs.slowquery")
 

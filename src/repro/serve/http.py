@@ -34,7 +34,11 @@ text exposition):
 Every response is also counted into the engine's metrics registry
 (``repro_http_requests_total{route,status}`` +
 ``repro_http_request_ms{route}``), so the server observes itself through
-the same ``/metrics`` surface it serves.
+the same ``/metrics`` surface it serves.  Profiler spans name the server's
+seams: ``repro.http.parse`` and ``repro.http.respond`` on the event loop,
+``repro.http.search`` (with ``repro.http.decode`` / ``repro.http.encode``)
+on the executor thread; a search's wait for that thread is the
+``repro_http_executor_wait_ms`` histogram.
 
 Status mapping — the error taxonomy the engine grew for exactly this:
 
@@ -77,6 +81,7 @@ from repro.engine import (
     RetrievalEngine,
     SearchRequest,
 )
+from repro.obs import span
 from repro.serve.quota import QuotaExceeded, TenantQuotas
 
 _REASONS = {
@@ -136,10 +141,14 @@ class AsyncHTTPBase:
     route_paths: Tuple[Tuple[str, str], ...] = ()
 
     def __init__(self, *, host: str = "127.0.0.1", port: int = 0,
-                 max_body: int = 64 << 20):
+                 max_body: int = 64 << 20, spans: bool = True):
         self._host = host
         self._port = int(port)
         self.max_body = int(max_body)
+        self._spans = bool(spans)
+        # per executor thread: how long the running handler's request
+        # waited for a thread (`_in_executor`)
+        self._tls = threading.local()
         self._server: Optional[asyncio.base_events.Server] = None
 
     # -- subclass surface ----------------------------------------------------
@@ -230,19 +239,21 @@ class AsyncHTTPBase:
                               status: int, payload: Dict,
                               headers: Dict[str, str],
                               keep_alive: bool) -> None:
-        if isinstance(payload, _Raw):
-            data, content_type = payload.data, payload.content_type
-        else:
-            data, content_type = json.dumps(payload).encode(), \
-                "application/json"
-        head = [
-            f"HTTP/1.1 {status} {_REASONS.get(status, 'Unknown')}",
-            f"Content-Type: {content_type}",
-            f"Content-Length: {len(data)}",
-            f"Connection: {'keep-alive' if keep_alive else 'close'}",
-        ]
-        head += [f"{k}: {v}" for k, v in headers.items()]
-        writer.write(("\r\n".join(head) + "\r\n\r\n").encode() + data)
+        with span("http.respond", self._spans):
+            if isinstance(payload, _Raw):
+                data, content_type = payload.data, payload.content_type
+            else:
+                data, content_type = json.dumps(payload).encode(), \
+                    "application/json"
+            head = [
+                f"HTTP/1.1 {status} {_REASONS.get(status, 'Unknown')}",
+                f"Content-Type: {content_type}",
+                f"Content-Length: {len(data)}",
+                f"Connection: {'keep-alive' if keep_alive else 'close'}",
+            ]
+            head += [f"{k}: {v}" for k, v in headers.items()]
+            writer.write(
+                ("\r\n".join(head) + "\r\n\r\n").encode() + data)
         await writer.drain()
 
     # -- routing -------------------------------------------------------------
@@ -261,36 +272,56 @@ class AsyncHTTPBase:
         self._observe(route, status, (time.perf_counter() - t0) * 1e3)
         return status, payload, headers
 
-    async def _route_inner(self, method: str, path: str,
-                           body: bytes) -> Tuple[int, Dict, Dict[str, str]]:
+    def _parse(self, method: str, path: str, body: bytes):
+        """Route the request and parse its body: ``(handler, body dict)``,
+        or ``(None, error response)``."""
         if body == b"__too_large__":
-            return 413, {"error": "request body exceeds "
-                                  f"{self.max_body} bytes"}, {}
+            return None, (413, {"error": "request body exceeds "
+                                         f"{self.max_body} bytes"}, {})
         path, _, qs = path.partition("?")
         params = dict(urllib.parse.parse_qsl(qs)) if qs else {}
         routes = self._routes()
         handler = routes.get((method, path))
         if handler is None:
             if any(p == path for (_, p) in routes):
-                return 405, {"error": f"{method} not allowed on {path}"}, {}
-            return 404, {"error": f"no route for {path}"}, {}
+                return None, (405, {"error": f"{method} not allowed on "
+                                             f"{path}"}, {})
+            return None, (404, {"error": f"no route for {path}"}, {})
         if method == "POST":
             try:
                 parsed = json.loads(body.decode() or "null")
             except (UnicodeDecodeError, json.JSONDecodeError) as e:
-                return 400, {"error": f"malformed JSON body: {e}"}, {}
+                return None, (400, {"error": f"malformed JSON body: {e}"},
+                              {})
             if not isinstance(parsed, dict):
-                return 400, {"error": "request body must be a JSON "
-                                      "object"}, {}
+                return None, (400, {"error": "request body must be a JSON "
+                                             "object"}, {})
         else:
             parsed = {}
         for key, value in params.items():      # body keys win over the qs
             parsed.setdefault(key, value)
+        return handler, parsed
+
+    def _in_executor(self, handler, body: Dict, t_queued: float):
+        """Run ``handler`` on an executor thread, first noting how long the
+        request waited for one (``t_queued`` is stamped on the event loop;
+        the wait crosses threads, so it is a number, not a span)."""
+        self._tls.executor_wait_ms = (time.perf_counter() - t_queued) * 1e3
+        return handler(body)
+
+    async def _route_inner(self, method: str, path: str,
+                           body: bytes) -> Tuple[int, Dict, Dict[str, str]]:
+        with span("http.parse", self._spans):
+            handler, parsed = self._parse(method, path, body)
+        if handler is None:
+            return parsed                      # the early error response
         loop = asyncio.get_event_loop()
         try:
             # handlers are blocking (driver futures, device work): run them
             # on the default executor so the accept loop stays responsive
-            payload = await loop.run_in_executor(None, handler, parsed)
+            payload = await loop.run_in_executor(
+                None, self._in_executor, handler, parsed,
+                time.perf_counter())
             if isinstance(payload, tuple):     # (payload, extra headers)
                 payload, headers = payload
                 return 200, payload, headers
@@ -360,7 +391,8 @@ class RetrievalHTTPServer(AsyncHTTPBase):
         replication: Optional[Any] = None,
         read_only: bool = False,
     ):
-        super().__init__(host=host, port=port, max_body=max_body)
+        super().__init__(host=host, port=port, max_body=max_body,
+                         spans=engine.config.obs.enabled)
         self.engine = engine
         self.driver = driver
         self.quotas = quotas if quotas is not None else TenantQuotas()
@@ -379,6 +411,10 @@ class RetrievalHTTPServer(AsyncHTTPBase):
         self._h_http = reg.histogram(
             "repro_http_request_ms", "HTTP request handling latency",
             labels=("route",))
+        self._h_exec_wait = reg.histogram(
+            "repro_http_executor_wait_ms",
+            "Search requests' wait for an executor thread: event loop "
+            "hand-off to handler start")
         self.quotas.bind_registry(reg)
 
     def _observe(self, route: str, status: int, dt_ms: float) -> None:
@@ -480,6 +516,12 @@ class RetrievalHTTPServer(AsyncHTTPBase):
         return out
 
     def _do_search(self, body: Dict) -> Tuple[Dict, Dict[str, str]]:
+        wait_ms = getattr(self._tls, "executor_wait_ms", 0.0)
+        self._h_exec_wait.observe(wait_ms)
+        with span("http.search", self._spans, executor_wait_ms=wait_ms):
+            return self._search(body)
+
+    def _search(self, body: Dict) -> Tuple[Dict, Dict[str, str]]:
         tenant = self._check_tenant(body)
         # Quota-lifecycle discipline: EVERYTHING that can reject the
         # request (tenant check, query parsing, SearchRequest validation)
@@ -492,7 +534,8 @@ class RetrievalHTTPServer(AsyncHTTPBase):
         # always runs exactly once and an in-flight slot can never leak
         # (the regression test hammers these paths and asserts
         # quotas.inflight returns to zero).
-        query = np.asarray(_body_field(body, "query"), np.float32)
+        with span("http.decode", self._spans):
+            query = np.asarray(_body_field(body, "query"), np.float32)
         request = SearchRequest(
             query=query,
             k=body.get("k"),
@@ -520,22 +563,21 @@ class RetrievalHTTPServer(AsyncHTTPBase):
             headers["degraded"] = str(result.degraded_level)
         if self.driver.cache is not None:
             headers["cache"] = "hit" if result.cached else "miss"
+        with span("http.encode", self._spans):
+            ids = result.doc_ids[live].tolist()
+            scores = result.scores[live].astype(float).tolist()
         return {
-            "ids": result.doc_ids[live].tolist(),
-            "scores": result.scores[live].astype(float).tolist(),
+            "ids": ids,
+            "scores": scores,
             "request_id": result.request_id,
             "store_generation": result.store_generation,
             "latency_ms": st.latency_ms,
             "cached": result.cached,
             "degraded_level": result.degraded_level,
-            # latency decomposition: queue_ms + compute_ms ~= latency_ms;
-            # stage0/rescore split the compute only under obs.stage_fences
-            # (null otherwise — the keys are always present)
+            # latency decomposition: queue_ms + compute_ms ~= latency_ms
             "spans": {
                 "queue_ms": st.queue_ms,
                 "compute_ms": st.compute_ms,
-                "stage0_ms": st.stage0_ms,
-                "rescore_ms": st.rescore_ms,
             },
         }, headers
 

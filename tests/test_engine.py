@@ -352,9 +352,3 @@ class TestStatsAndProfile:
         eng.run_until_idle()
         # 3 requests -> one bucket-4 batch with 1 padded slot
         assert eng.stats.n_padded_slots == 1
-
-    def test_profile_stages_covers_schedule(self):
-        eng, db = make_engine()
-        prof = eng.profile_stages(db[:2], runs=1)
-        assert [p["dim"] for p in prof] == [s.dim for s in eng.sched.stages]
-        assert all(p["ms"] >= 0 for p in prof)

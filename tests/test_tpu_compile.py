@@ -116,6 +116,13 @@ def test_ivf_kernel_search_compiles(one_chip, dtype):
         pq_oversample=PQ_OVERSAMPLE if pq else 1, interpret=False,
     ).compile()
     assert _kernel_calls(compiled) >= 1
+    text = compiled.as_text()
+    # the device trace names the kernel by its HLO instruction (the
+    # benchmark's ivf_scan_roofline matches it) and files it under the
+    # program's stage0/scan named scope
+    kernel = "%_ivf_scan_call" if not pq else "%_pq_ivf_call"
+    lines = [ln for ln in text.splitlines() if kernel in ln.split("=")[0]]
+    assert lines and all("/stage0/scan/" in ln for ln in lines)
 
 
 @pytest.mark.parametrize("pq_m", [PQ_M, PQ_M // 2])
