@@ -331,10 +331,12 @@ def _kernel_search_jit(
     *, n_probe, index_dims, metric, pack_meta, pq_oversample, interpret,
 ):
     """The fused IVF program.  Named scopes: ``stage0`` with ``probe``
-    (centroid scores and top-k), ``member_mask`` (the validity gather over
-    the member table), ``scan`` (the fused kernel) and ``tail`` (the
-    un-indexed rows); then ``rescore`` (the ladder)."""
-    from repro.kernels.ivf_scan import ivf_scan_topk
+    (centroid scores and top-k), ``member_mask`` (the per-query id table:
+    the probed lists' member ids, with the validity gather over those
+    slots alone, or over the whole member table once when the probes of
+    the batch reach as many slots), ``scan`` (the fused kernel) and
+    ``tail`` (the un-indexed rows); then ``rescore`` (the ladder)."""
+    from repro.kernels.ivf_scan import ivf_scan_topk, probed_ids
     from repro.kernels.pq_scan import pq_ivf_scan_topk
     from repro.core.progressive import rescore_ladder
 
@@ -345,14 +347,12 @@ def _kernel_search_jit(
             cs = T._METRICS[metric](q[:, :d_probe], centroids, cent_sq)
             _, probe = jax.lax.top_k(-cs, min(n_probe, centroids.shape[0]))
 
-        # mask every unreturnable slot to -1 BEFORE the kernel: list padding
-        # is already -1, tombstoned rows come from the live validity bits
-        # (the packed member vectors are a build-time snapshot)
-        member_ids = lists
-        if valid is not None:
-            with jax.named_scope("member_mask"):
-                member_ids = jnp.where(
-                    (lists >= 0) & valid[jnp.maximum(lists, 0)], lists, -1)
+        # mask every unreturnable slot the probes scan to -1 BEFORE the
+        # kernel: list padding is already -1, tombstoned rows come from the
+        # live validity bits (the packed member vectors are a build-time
+        # snapshot), and valid is per-dispatch data, so this cannot be cached
+        with jax.named_scope("member_mask"):
+            member_ids = probed_ids(lists, probe, valid)
 
         pack = {
             "rows": pack_rows, "sq": pack_sq, "scale": pack_scale,

@@ -19,7 +19,8 @@ collapses them into **one streaming read of the probed lists' member rows**:
   slab while list ``p`` is being scored.
 * Scores are truncated-dim L2 (``‖x‖² − 2 q·x`` on the MXU, f32 accumulate)
   with padding (``-1`` ids) and tombstoned rows masked to +inf in-kernel via
-  the caller-masked id table.
+  a per-query id table (`probed_ids`): the probed lists' member ids, masked
+  by the live validity bits over those slots alone.
 * A running top-k rides in VMEM scratch across the sequential
   (probe × chunk) grid axis, merged by `distance_topk.merge_topk` — only
   the final (Q, k) result ever reaches HBM.
@@ -178,6 +179,36 @@ def update_pack(pack: Dict, db: Array, ids, dests) -> Dict:
     return out
 
 
+def _mask_ids(ids: Array, valid: Array) -> Array:
+    """-1 wherever ``ids`` is padding or names a row ``valid`` rules out."""
+    return jnp.where((ids >= 0) & valid[jnp.maximum(ids, 0)], ids, -1)
+
+
+def probed_ids(lists: Array, probe: Array,
+               valid: Optional[Array] = None) -> Array:
+    """The per-query id table the list-major kernels read.
+
+    Args:
+      lists: (n_lists, max_len) int32 member table, -1 padded.
+      probe: (Q, n_probe) int32 probed list indices.
+      valid: optional (N,) bool live bits of this dispatch (tombstones and
+             any filter mask); the packed member *vectors* are a build-time
+             snapshot and are not consulted for liveness.
+
+    Returns:
+      (Q, n_probe, max_len) int32: row ``[i, p]`` is list ``probe[i, p]``'s
+      member ids with every slot ``valid`` rules out set to -1.  The
+      validity gather covers the probed slots alone, Q·n_probe·max_len of
+      them; where that would reach the whole table (Q·n_probe ≥ n_lists)
+      the table is masked once and its probed rows gathered instead.  Both
+      orders give the same table.
+    """
+    if valid is not None and probe.size >= lists.shape[0]:
+        return _mask_ids(lists, valid)[probe]
+    ids = lists[probe]
+    return ids if valid is None else _mask_ids(ids, valid)
+
+
 def _kernel(
     probe_ref, q_ref, rows_ref, sq_ref, ids_ref, out_s_ref, out_i_ref,
     best_s, best_i,
@@ -214,7 +245,7 @@ def _kernel(
     static_argnames=("k", "dim", "max_len", "block_m", "interpret"),
 )
 def _ivf_scan_call(
-    q, probe, rows, sq, member_ids, *, k, dim, max_len, block_m, interpret,
+    q, probe, rows, sq, ids, *, k, dim, max_len, block_m, interpret,
 ):
     nq = q.shape[0]
     n_lists = sq.shape[0]
@@ -233,6 +264,9 @@ def _ivf_scan_call(
     def list_idx(i, j, probe):
         return (probe[i, j // nc], 0, j % nc)
 
+    def probed_idx(i, j, probe):
+        return (i * n_probe + j // nc, 0, j % nc)
+
     def query_idx(i, j, probe):
         return (i, 0, 0)
 
@@ -245,7 +279,7 @@ def _ivf_scan_call(
                 pl.BlockSpec((sqz, 1, dim), query_idx),
                 pl.BlockSpec((block_m, dim), rows_idx),
                 pl.BlockSpec((sqz, 1, block_m), list_idx),
-                pl.BlockSpec((sqz, 1, block_m), list_idx),
+                pl.BlockSpec((sqz, 1, block_m), probed_idx),
             ],
             out_specs=[
                 pl.BlockSpec((sqz, 1, k), query_idx),
@@ -265,8 +299,20 @@ def _ivf_scan_call(
         ),
         interpret=interpret,
     )(probe, q[:, None, :], rows, sq.reshape(n_lists, 1, max_len),
-      member_ids.reshape(n_lists, 1, max_len))
+      ids.reshape(nq * n_probe, 1, max_len))
     return out_s[:, 0], out_i[:, 0]
+
+
+def _padded_ids(member_ids: Array, probe: Array, max_len: int) -> Array:
+    """The per-query id table, its slots -1 padded to the pack's
+    ``max_len``; a 2-D member table has its probed rows gathered first."""
+    ids = member_ids
+    if ids.ndim == 2:
+        ids = probed_ids(ids, probe)
+    pad = max_len - ids.shape[2]
+    if pad:
+        ids = jnp.pad(ids, ((0, 0), (0, 0), (0, pad)), constant_values=-1)
+    return ids
 
 
 def ivf_scan_topk(
@@ -285,11 +331,13 @@ def ivf_scan_topk(
       probe:      (Q, n_probe) int32 — per-query probed list indices, all in
                   ``[0, n_lists)`` and **distinct within a row** (duplicated
                   probes would double-count their members in the top-k).
-      member_ids: (n_lists, max_len) int32 global doc ids with every
-                  unreturnable slot already masked to -1 (list padding AND
-                  tombstoned rows — mask with the live validity bits before
-                  calling; the packed member *vectors* are a build-time
-                  snapshot and are not consulted for liveness).
+      member_ids: (Q, n_probe, max_len) int32 per-query id table
+                  (`probed_ids`): global doc ids of each probed list with
+                  every unreturnable slot masked to -1 (list padding AND
+                  tombstoned rows; the packed member *vectors* are a
+                  build-time snapshot and are not consulted for liveness).
+                  A (n_lists, max_len) member table, already masked, is
+                  also taken and its probed rows gathered here.
       pack:       `pack_ivf_lists` output (member slabs at stage-0 dim).
       k:          neighbours kept (static).
       interpret:  interpret mode for CPU validation.
@@ -311,12 +359,9 @@ def ivf_scan_topk(
         # fold the query onto the codes' grid outside the kernel: int32-ish
         # inner products rescaled per-dim by s², db side stays int8
         qd = quant.fold_int8_query(qd, pack["scale"])
-    pad = max_len - member_ids.shape[1]
-    if pad:
-        member_ids = jnp.pad(member_ids, ((0, 0), (0, pad)),
-                             constant_values=-1)
+    ids = _padded_ids(member_ids, probe, max_len)
     return _ivf_scan_call(
-        qd, probe.astype(jnp.int32), pack["rows"], pack["sq"], member_ids,
+        qd, probe.astype(jnp.int32), pack["rows"], pack["sq"], ids,
         k=k, dim=d0, max_len=max_len, block_m=bm, interpret=interpret,
     )
 

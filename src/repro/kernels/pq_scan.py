@@ -14,9 +14,10 @@ VMEM** for the whole scan and streams only the uint8 code slabs:
   one-hot per subspace that contracts with LUT row m on the MXU; the M
   (1, block_m) products sum to the ADC score row.
 * Padding and tombstones are masked in-kernel via the caller-masked id
-  table (-1 ids score +inf), and the running top-k rides in VMEM scratch
-  (merged by `distance_topk.merge_topk`); only the final (Q, k) result
-  ever reaches HBM.
+  table (-1 ids score +inf; per query in the list-major scan, see
+  `repro.kernels.ivf_scan.probed_ids`), and the running top-k rides in
+  VMEM scratch (merged by `distance_topk.merge_topk`); only the final
+  (Q, k) result ever reaches HBM.
 
 Two grid shapes share the kernel body:
 
@@ -167,10 +168,9 @@ def pq_scan_topk(
 
 @functools.partial(
     jax.jit, static_argnames=("k", "max_len", "block_m", "interpret"))
-def _pq_ivf_call(lut, probe, codes, member_ids, *, k, max_len, block_m,
+def _pq_ivf_call(lut, probe, codes, ids, *, k, max_len, block_m,
                  interpret):
     nq, m, c = lut.shape
-    n_lists = member_ids.shape[0]
     n_probe = probe.shape[1]
     nc = max_len // block_m
     nj = n_probe * nc
@@ -179,8 +179,8 @@ def _pq_ivf_call(lut, probe, codes, member_ids, *, k, max_len, block_m,
     def codes_idx(i, j, probe):
         return (probe[i, j // nc] * nc + j % nc, 0)
 
-    def list_idx(i, j, probe):
-        return (probe[i, j // nc], 0, j % nc)
+    def probed_idx(i, j, probe):
+        return (i * n_probe + j // nc, 0, j % nc)
 
     def kern(probe_ref, *args):
         _pq_body(*args)
@@ -192,13 +192,13 @@ def _pq_ivf_call(lut, probe, codes, member_ids, *, k, max_len, block_m,
         in_specs=[
             pl.BlockSpec((sqz, m, c), lambda i, j, probe: (i, 0, 0)),
             pl.BlockSpec((block_m, m), codes_idx),
-            pl.BlockSpec((sqz, 1, block_m), list_idx),
+            pl.BlockSpec((sqz, 1, block_m), probed_idx),
         ],
         out_specs=[out_spec, out_spec],
         scratch_shapes=_scratch(k),
     )
     return _pq_call(kern, grid_spec, nq, k, interpret, probe, lut, codes,
-                    member_ids.reshape(n_lists, 1, max_len))
+                    ids.reshape(nq * n_probe, 1, max_len))
 
 
 def pq_ivf_scan_topk(
@@ -214,17 +214,20 @@ def pq_ivf_scan_topk(
     """Fused IVF-PQ stage 0: probe-driven LUT scan over list-major codes.
 
     The list-major twin of `repro.kernels.ivf_scan.ivf_scan_topk`: same
-    scalar-prefetched probe table, same double-buffered slab streaming,
-    same in-VMEM top-k — but the member slabs hold PQ codes
-    (`pack_ivf_lists(dtype='pq')`) and scoring is the resident-LUT one-hot
-    contraction instead of a distance matmul.
+    scalar-prefetched probe table, same per-query id table, same
+    double-buffered slab streaming, same in-VMEM top-k — but the member
+    slabs hold PQ codes (`pack_ivf_lists(dtype='pq')`) and scoring is the
+    resident-LUT one-hot contraction instead of a distance matmul.
 
     Args:
       q:          (Q, D) queries (only ``[:, :pack['dim']]`` feeds the LUT;
                   ignored when ``lut`` is given).
       probe:      (Q, n_probe) int32 probed list indices (distinct per row).
-      member_ids: (n_lists, max_len) int32 global ids, every unreturnable
-                  slot pre-masked to -1 (padding AND tombstones).
+      member_ids: (Q, n_probe, max_len) int32 per-query id table
+                  (`repro.kernels.ivf_scan.probed_ids`), every unreturnable
+                  slot masked to -1 (padding AND tombstones); or a
+                  pre-masked (n_lists, max_len) member table, whose probed
+                  rows are gathered here.
       pack:       `pack_ivf_lists(..., dtype='pq')` output.
       k:          neighbours kept (static).
       interpret:  interpret mode for CPU validation.
@@ -235,6 +238,7 @@ def pq_ivf_scan_topk(
        (Q, k) int32 global doc ids, -1 empties).
     """
     from repro.core.pq import pq_lut
+    from repro.kernels.ivf_scan import _padded_ids
 
     if pack["dtype"] != "pq":
         raise ValueError(
@@ -247,13 +251,10 @@ def pq_ivf_scan_topk(
     nq = lut.shape[0]
     if nq == 0:
         return (jnp.zeros((0, k), jnp.float32), jnp.zeros((0, k), jnp.int32))
-    pad = max_len - member_ids.shape[1]
-    if pad:
-        member_ids = jnp.pad(member_ids, ((0, 0), (0, pad)),
-                             constant_values=-1)
+    ids = _padded_ids(member_ids, probe, max_len)
     return _pq_ivf_call(
         lut.astype(jnp.float32), probe.astype(jnp.int32), pack["rows"],
-        member_ids, k=k, max_len=max_len, block_m=bm, interpret=interpret)
+        ids, k=k, max_len=max_len, block_m=bm, interpret=interpret)
 
 
 def flat_stage0_bytes_model(

@@ -195,6 +195,66 @@ class TestIvfScan:
             np.sort(np.asarray(s1), axis=1), np.sort(np.asarray(s2), axis=1),
             rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("dtype", ["float32", "int8", "pq"])
+    @pytest.mark.parametrize("nq", [2, 4])   # Q·n_probe 10 < 12 lists < 20
+    def test_parity_under_tombstones_and_filter_mask(self, dtype, nq):
+        """The kernel program masks only the probed lists' slots (or, once
+        Q·n_probe reaches n_lists, the whole member table): with tombstones
+        and a filter-style mask inside the probed lists it answers exactly
+        as the kernel fed a member table masked whole before the probe
+        gather (the oracle), and, for f32 slabs, with the id sets of
+        `ivf_progressive_search_sched`."""
+        import jax
+        from repro.core import make_schedule
+        from repro.core import truncated as T
+        from repro.core.ivf import (build_ivf, ivf_progressive_search_kernel,
+                                    ivf_progressive_search_sched)
+        from repro.core.pq import train_pq
+        from repro.kernels.ivf_scan import probed_ids
+        rng = np.random.default_rng(29)
+        n, d, d0, n_lists, n_probe = 400, 64, 8, 12, 5
+        db = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+        q = jnp.asarray(rng.normal(size=(nq, d)).astype(np.float32))
+        sched = make_schedule(d0, d, 32, final_k=5)
+        ivf = build_ivf(db, n_lists)
+        lists = np.asarray(ivf["lists"])
+        _, probe = jax.lax.top_k(-T.l2_scores(q, ivf["centroids"]), n_probe)
+        probe = np.asarray(probe)
+        tombstone = rng.random(n) < 0.15
+        filtered = np.arange(n) % 3 == 0             # a tenant's bitmask
+        valid = ~tombstone & ~filtered
+        probed = lists[probe]
+        probed = probed[probed >= 0]
+        assert tombstone[probed].any() and filtered[probed].any()
+        masked = np.where((lists >= 0) & valid[np.maximum(lists, 0)],
+                          lists, -1).astype(np.int32)
+        # either order of mask and gather gives the same per-query table
+        np.testing.assert_array_equal(
+            np.asarray(probed_ids(jnp.asarray(lists), jnp.asarray(probe),
+                                  jnp.asarray(valid))),
+            masked[probe])
+
+        cb = (train_pq(db[:, :d0], m=4, n_codes=32, n_iter=4)
+              if dtype == "pq" else None)
+        pack = pack_ivf_lists(db, ivf["lists"], dim=d0, dtype=dtype,
+                              block_m=16, pq_codebooks=cb)
+        kw = dict(n_probe=n_probe, pack=pack, interpret=True,
+                  pq_oversample=2 if dtype == "pq" else 1)
+        s, i = ivf_progressive_search_kernel(
+            q, db, ivf["centroids"], ivf["lists"], sched,
+            valid=jnp.asarray(valid), **kw)
+        so, io = ivf_progressive_search_kernel(
+            q, db, ivf["centroids"], jnp.asarray(masked), sched, **kw)
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(io))
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(so))
+        ia = np.asarray(i)
+        assert valid[ia[ia >= 0]].all()
+        if dtype == "float32":
+            _, ix = ivf_progressive_search_sched(
+                q, db, ivf["centroids"], ivf["lists"], sched,
+                n_probe=n_probe, valid=jnp.asarray(valid))
+            assert _id_sets(i) == _id_sets(ix)
+
     def test_int8_pack_composes(self):
         """int8 member slabs: valid results, near-f32 ranking quality."""
         rng = np.random.default_rng(23)

@@ -15,6 +15,9 @@ process at a time may load the TPU runtime, and every test worker imports
 this file.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -60,10 +63,10 @@ def _shapes(sharding, **shapes):
             for name, spec in shapes.items()}
 
 
-def _store(sharding):
+def _store(sharding, nq=Q):
     return _shapes(
         sharding,
-        q=((Q, DIM), jnp.float32),
+        q=((nq, DIM), jnp.float32),
         db=((N_ROWS, DIM), jnp.float32),
         valid=((N_ROWS,), jnp.bool_),
         sq_prefix=((N_ROWS, len(DIMS)), jnp.float32),
@@ -84,18 +87,15 @@ def test_flat_progressive_search_compiles(one_chip):
     assert mem.argument_size_in_bytes >= N_ROWS * DIM * 4
 
 
-@pytest.mark.parametrize("dtype", ["float32", "int8", "pq"])
-def test_ivf_kernel_search_compiles(one_chip, dtype):
-    """`ivf_progressive_search_kernel`'s program: probe, fused stage-0
-    (`ivf_scan` f32/int8, or `pq_scan`'s list-major scan), tail merge and
-    the rescore ladder."""
+def _compile_ivf_search(sharding, dtype, nq):
+    """`_kernel_search_jit` compiled at ``nq`` queries; its HLO text."""
     from repro.core.ivf import _kernel_search_jit
 
-    s = _store(one_chip)
+    s = _store(sharding, nq)
     slots = N_LISTS * MAX_LEN
     pq = dtype == "pq"
     p = _shapes(
-        one_chip,
+        sharding,
         centroids=((N_LISTS, DIM), jnp.float32),
         lists=((N_LISTS, MAX_LEN), jnp.int32),
         cent_sq=((N_LISTS,), jnp.float32),
@@ -123,6 +123,46 @@ def test_ivf_kernel_search_compiles(one_chip, dtype):
     kernel = "%_ivf_scan_call" if not pq else "%_pq_ivf_call"
     lines = [ln for ln in text.splitlines() if kernel in ln.split("=")[0]]
     assert lines and all("/stage0/scan/" in ln for ln in lines)
+    return text
+
+
+def _member_mask_sizes(text):
+    """Element counts of every HLO value computed under
+    ``stage0/member_mask`` (fused computations' bodies included)."""
+    sizes = []
+    for ln in text.splitlines():
+        if "/stage0/member_mask/" not in ln:
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?%\S+\s*=\s*(.*?)\s+[\w-]+\(", ln)
+        for dims in re.findall(r"\[([\d,]*)\]", m.group(1) if m else ""):
+            sizes.append(math.prod(int(x) for x in dims.split(",") if x))
+    return sizes
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8", "pq"])
+def test_ivf_kernel_search_compiles(one_chip, dtype):
+    """`ivf_progressive_search_kernel`'s program: probe, fused stage-0
+    (`ivf_scan` f32/int8, or `pq_scan`'s list-major scan), tail merge and
+    the rescore ladder.  With Q·n_probe under n_lists the validity gather
+    covers the probed slots alone: nothing under ``stage0/member_mask`` is
+    the size of the member table."""
+    assert Q * N_PROBE < N_LISTS
+    sizes = _member_mask_sizes(_compile_ivf_search(one_chip, dtype, Q))
+    assert Q * N_PROBE * MAX_LEN in sizes
+    assert N_LISTS * MAX_LEN not in sizes
+    assert max(sizes) < N_LISTS * MAX_LEN
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8", "pq"])
+def test_ivf_wide_probes_mask_the_member_table_once(one_chip, dtype):
+    """At Q·n_probe ≥ n_lists masking per query would gather more than the
+    member table: the program masks the whole table once, then gathers
+    the probed rows into the same per-query layout."""
+    nq = 256
+    assert nq * N_PROBE >= N_LISTS
+    sizes = _member_mask_sizes(_compile_ivf_search(one_chip, dtype, nq))
+    assert N_LISTS * MAX_LEN in sizes
+    assert nq * N_PROBE * MAX_LEN in sizes
 
 
 @pytest.mark.parametrize("pq_m", [PQ_M, PQ_M // 2])
