@@ -18,6 +18,14 @@ file of its own under ``bench/``:
 
 So a later cell, configuration or metric is new files and new entries,
 with no edit to a file that is already there.
+
+Layout.  A configuration is served by one process on one chip, unless its
+file has ``"replicas": N`` with N > 1: then N one-chip replicas, each
+building the same engine from the same seed, serve behind the program's
+router.  The run's own process is replica 0 and holds chip 0; replicas
+1..N-1 are processes of their own, one chip each; the router is the
+program's launcher (``repro.launch.serve --serve-http --role router``) in a
+process that holds no chip.  Such a cell asks for N chips.
 """
 
 from __future__ import annotations
@@ -73,6 +81,11 @@ def load_cell(name: str, root: str = ROOT, bench: str = BENCH) -> Cell:
                        f"known: {sorted(by_name)}")
     w = by_name[name]
     config = _json(os.path.join(bench, "configs", f"{w['config']}.json"))
+    n = int(config.get("replicas", 1))
+    if n > 1 and int(w["chips"]) != n:
+        raise ValueError(f"workload {name!r} asks for {w['chips']} chips, "
+                         f"but its configuration {w['config']!r} is served "
+                         f"by {n} one-chip replicas")
     traffic = _json(os.path.join(bench, "traffic", f"{w['traffic']}.json"))
     own = os.path.join(bench, "traffic", f"{w['traffic']}.{w['config']}.json")
     if os.path.isfile(own):

@@ -9,7 +9,14 @@ for:
 ``BENCHMARK.json`` names the cells; each is a configuration (a deployment,
 ``bench/configs/``) under a traffic mix (``bench/traffic/``).  One process
 holds the chip and serves; load generator processes that never import JAX
-send the traffic.  The last line of standard output is one JSON object with
+send the traffic.  A configuration with ``"replicas": N`` is served by N
+one-chip replicas behind the program's router: this process is replica 0
+on chip 0, each other replica a process of its own on one chip
+(``bench/harness/replica.py``), and the router the program's own launcher
+(``python -m repro.launch.serve --serve-http --role router``) in a process
+that holds no chip; the load generators send to the router.
+
+The last line of standard output is one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, last,
 ``checks``: each number compared with the plain reference beside its limit.
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
@@ -52,7 +59,7 @@ def main(argv=None) -> int:
 
     try:
         c = spec.load_cell(args.workload)
-    except (KeyError, FileNotFoundError) as e:
+    except (KeyError, FileNotFoundError, ValueError) as e:
         print(f"bench/run.py: {e}", file=sys.stderr)
         return 2
     try:

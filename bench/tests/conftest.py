@@ -20,10 +20,12 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 # the tiny cells a temporary checkout adds as files
 TINY = {
-    "configs": ["tiny-flat", "tiny-ivf8"],
+    "configs": ["tiny-flat", "tiny-ivf8", "tiny-flat-replicas2"],
     "workloads": [("tiny-flat.tiny-poisson", "tiny-flat", "tiny-poisson"),
                   ("tiny-ivf8.tiny-poisson", "tiny-ivf8", "tiny-poisson"),
-                  ("tiny-flat.tiny-closed", "tiny-flat", "tiny-closed")],
+                  ("tiny-flat.tiny-closed", "tiny-flat", "tiny-closed"),
+                  ("tiny-flat-replicas2.tiny-closed", "tiny-flat-replicas2",
+                   "tiny-closed")],
 }
 
 
@@ -43,8 +45,10 @@ def add_tiny_cells(root: str) -> None:
             "name": name, "source": "test fixture", "reduced": [],
             "file": f"bench/configs/{name}.json", "why": "test fixture"})
     for name, config, traffic in TINY["workloads"]:
+        with open(os.path.join(FIXTURES, "configs", f"{config}.json")) as f:
+            chips = json.load(f).get("replicas", 1)
         spec["workloads"].append({"name": name, "config": config,
-                                  "traffic": traffic, "chips": 1,
+                                  "traffic": traffic, "chips": chips,
                                   "why": "test fixture"})
         for m in spec["end_to_end"] + spec["per_layer"]:
             mix = "poisson" if "poisson" in traffic else "closed"
@@ -52,7 +56,8 @@ def add_tiny_cells(root: str) -> None:
             uses = {"search_p95_ms": "poisson", "search_p50_ms": "poisson",
                     "search_qps": "closed"}.get(m["name"], reads)
             if "workloads" in m and uses in (mix, None) \
-                    and m["name"] != "ivf_scan_roofline":
+                    and m["name"] != "ivf_scan_roofline" \
+                    and (chips > 1 or not m["name"].startswith("replica_")):
                 m["workloads"].append(name)
     with open(path, "w") as f:
         json.dump(spec, f, indent=1)

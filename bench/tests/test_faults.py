@@ -16,11 +16,23 @@ from harness.faults import PLANTED
 
 @pytest.mark.parametrize("fault", ["alter_an_answer", "drop_half_the_batch"])
 @pytest.mark.parametrize("name", ["tiny-flat.tiny-closed",
-                                  "tiny-ivf8.tiny-poisson"])
+                                  "tiny-ivf8.tiny-poisson",
+                                  "tiny-flat-replicas2.tiny-closed"])
 def test_a_broken_path_is_not_correct(tiny_root, name, fault):
     r = cell.run(tiny_cell(tiny_root, name), 4, 2.0, False,
                  t_start=time.monotonic(), require_tpu=False,
                  tamper=PLANTED[fault])
+    assert r["correct"] is False
+    assert any(c["value"] > c["limit"] for c in r["checks"].values())
+
+
+@pytest.mark.parametrize("fault", ["alter_an_answer", "drop_half_the_batch"])
+def test_a_broken_replica_behind_the_router_is_not_correct(tiny_root, fault):
+    # planted in replica 1, a process of its own: the answers of every
+    # replica are compared, not only those of the harness's own
+    r = cell.run(tiny_cell(tiny_root, "tiny-flat-replicas2.tiny-closed"), 4,
+                 2.0, False, t_start=time.monotonic(), require_tpu=False,
+                 fault=fault)
     assert r["correct"] is False
     assert any(c["value"] > c["limit"] for c in r["checks"].values())
 

@@ -54,7 +54,9 @@ print(json.dumps(r))
 
 @pytest.mark.parametrize("name,trace", [
     ("tiny-flat.tiny-poisson", False), ("tiny-flat.tiny-closed", True),
-    ("tiny-ivf8.tiny-poisson", False)])
+    ("tiny-ivf8.tiny-poisson", False),
+    ("tiny-flat-replicas2.tiny-closed", False),
+    ("tiny-flat-replicas2.tiny-closed", True)])
 def test_cells_added_as_files_run(tiny_root, name, trace):
     code = DRIVE.format(bench=os.path.join(tiny_root, "bench"),
                         src=os.path.join(ROOT, "src"), name=name, seed=3,
@@ -70,11 +72,18 @@ def test_cells_added_as_files_run(tiny_root, name, trace):
     assert list(r)[-1] == "checks"
     assert r["attempted"] > 0 and r["failed"] == 0
     assert r["device"]["platform"] == "cpu"
+    replicas = 2 if "replicas2" in name else 1
+    assert r["device"]["count"] == early["device_count"]
     metrics = r["metrics"]
+    if replicas > 1:
+        assert r["device"]["count"] == replicas
+        assert ("replica_min_share.closed" in metrics) == trace
     if trace:
         assert {"http_self_ms.closed", "batch_fill.closed",
                 "dispatch_ms.closed", "device_idle.closed"} <= set(metrics)
         assert r["device"]["window_s"] > 0 and "breakdown" in r
+        if replicas > 1:            # every replica served through the router
+            assert 0 < metrics["replica_min_share.closed"]["value"] <= 1
     else:
         want = {"recall_at_10", "setup_s"} | (
             {"search_p95_ms", "search_p50_ms"} if "poisson" in name
@@ -82,3 +91,19 @@ def test_cells_added_as_files_run(tiny_root, name, trace):
         assert want <= set(metrics)
         assert 0 < metrics["recall_at_10"]["value"] <= 1
     assert "check failed:" in p.stderr.splitlines()[-len(r["checks"])]
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_cell_whose_chips_differ_from_its_replicas_is_refused(
+        tiny_root, tmp_path, chips):
+    from harness import spec
+
+    with open(os.path.join(tiny_root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    for w in bench["workloads"]:
+        if w["config"] == "tiny-flat-replicas2":
+            w["chips"] = chips
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(ValueError, match="replicas"):
+        spec.load_cell("tiny-flat-replicas2.tiny-closed", root=str(tmp_path),
+                       bench=os.path.join(tiny_root, "bench"))
