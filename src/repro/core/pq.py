@@ -248,11 +248,12 @@ def _stage0_ids(codes, valid, row_limit):
 
 def _finish(q, rescore_db, sched, scores, cand, *, valid, extra_cand,
             metric):
-    """Shared post-stage-0 path: tail injection + the rescore ladder."""
+    """Shared post-stage-0 path: tail injection (``stage0/tail``) + the
+    rescore ladder."""
     from repro.core.progressive import rescore_ladder
     from repro.core.quant import quant_rest_stages
 
-    with jax.named_scope("stage0"):
+    with jax.named_scope("stage0"), jax.named_scope("tail"):
         cand = T.inject_candidates(cand, extra_cand)
     return rescore_ladder(
         q, rescore_db, cand,
@@ -282,7 +283,9 @@ def pq_progressive_search(
     cuts the pool back).  The mutable-corpus extensions (``db``/``valid``/
     ``row_limit``/``extra_cand``) mean exactly what they mean for
     `repro.core.quant.quantized_progressive_search`.  Named scopes:
-    ``stage0`` (LUT, ADC scan, top-k), ``rescore``.
+    ``stage0`` with ``lut`` (the ADC tables), ``scan`` (the id mask, ADC
+    scan and top-k) and ``tail`` (the injected tail window); then
+    ``rescore``.
     """
     if metric != "l2":
         raise ValueError(
@@ -294,13 +297,15 @@ def pq_progressive_search(
     n0 = codes.shape[0]
     ds = idx["codebooks"].shape[0] * idx["codebooks"].shape[2]
     with jax.named_scope("stage0"):
-        lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
-        scores = pq_adc_scores(lut, codes)
-        ids = _stage0_ids(codes, valid, row_limit)
-        scores = jnp.where(ids[None, :] >= 0, scores, jnp.inf)
-        neg, cand = jax.lax.top_k(-scores, min(s0.k * oversample, n0))
-        # fully-masked slots must surface the -1 sentinel, not row 0
-        cand = jnp.where(jnp.isfinite(-neg), cand.astype(jnp.int32), -1)
+        with jax.named_scope("lut"):
+            lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
+        with jax.named_scope("scan"):
+            scores = pq_adc_scores(lut, codes)
+            ids = _stage0_ids(codes, valid, row_limit)
+            scores = jnp.where(ids[None, :] >= 0, scores, jnp.inf)
+            neg, cand = jax.lax.top_k(-scores, min(s0.k * oversample, n0))
+            # fully-masked slots must surface the -1 sentinel, not row 0
+            cand = jnp.where(jnp.isfinite(-neg), cand.astype(jnp.int32), -1)
     return _finish(q, rescore_db, sched, -neg, cand,
                    valid=valid, extra_cand=extra_cand, metric=metric)
 
@@ -326,8 +331,9 @@ def pq_progressive_search_kernel(
     `tests/test_kernels.py` enforces), but stage 0 runs
     `repro.kernels.pq_scan.pq_scan_topk`: the per-query (M, C) LUT stays
     VMEM-resident while uint8 code slabs stream HBM→VMEM once and the
-    running top-k never leaves VMEM.  Named scopes: ``stage0`` (LUT and the
-    fused scan), ``rescore``.
+    running top-k never leaves VMEM.  Named scopes: ``stage0`` with ``lut``,
+    ``scan`` (the id mask and the fused kernel) and ``tail``; then
+    ``rescore``.
     """
     from repro.kernels.pq_scan import pq_scan_topk
 
@@ -341,10 +347,12 @@ def pq_progressive_search_kernel(
     n0 = codes.shape[0]
     ds = idx["codebooks"].shape[0] * idx["codebooks"].shape[2]
     with jax.named_scope("stage0"):
-        lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
-        ids = _stage0_ids(codes, valid, row_limit)
-        scores, cand = pq_scan_topk(
-            lut, codes, ids, k=min(s0.k * oversample, n0), block_m=block_m,
-            interpret=interpret)
+        with jax.named_scope("lut"):
+            lut = pq_lut(q[:, :ds], idx["codebooks"], idx["cent_sq"])
+        with jax.named_scope("scan"):
+            ids = _stage0_ids(codes, valid, row_limit)
+            scores, cand = pq_scan_topk(
+                lut, codes, ids, k=min(s0.k * oversample, n0),
+                block_m=block_m, interpret=interpret)
     return _finish(q, rescore_db, sched, scores, cand,
                    valid=valid, extra_cand=extra_cand, metric=metric)

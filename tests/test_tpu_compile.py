@@ -187,6 +187,14 @@ def test_pq_flat_kernel_search_compiles(one_chip, pq_m):
         interpret=False,
     ).compile()
     assert _kernel_calls(compiled) >= 1
+    text = compiled.as_text()
+    # the benchmark's pq_scan_roofline finds the kernel by this HLO name,
+    # and stage0_device_ms its work by the stage0 sub-scopes
+    lines = [ln for ln in text.splitlines()
+             if "%_pq_scan_call" in ln.split("=")[0]]
+    assert lines and all("/stage0/scan/" in ln for ln in lines)
+    for scope in ("/stage0/lut/", "/stage0/scan/", "/stage0/tail/"):
+        assert scope in text, scope
 
 
 @pytest.mark.parametrize("block_q", [8, 64])

@@ -225,7 +225,7 @@ PROGRAMS = {
     "quantized_pq": (
         QuantizedConfig(codec="pq", pq_m=4, pq_codes=16, use_kernel=True),
         (core_pq, "pq_progressive_search_kernel"),
-        ("stage0", "rescore"),
+        ("stage0", "stage0/lut", "stage0/scan", "stage0/tail", "rescore"),
         [[0, 439, 508, 392, 383], [1, 437, 231, 47, 340],
          [2, 367, 103, 41, 325], [3, 79, 510, 360, 166],
          [4, 335, 328, 230, 438], [5, 373, 395, 204, 229]]),
